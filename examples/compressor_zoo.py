@@ -24,11 +24,11 @@ for comp, kind in [
         (TopKSVD(rank=4), "nuclear"),
         (TopKSVD(rank=4), "frobenius"),
         (ColumnTopK(0.25), "col_l2_dual"),
-        (Natural(), "frobenius"),
-        (Natural(), "linf"),
+        (Natural(use_pallas=False), "frobenius"),
+        (Natural(use_pallas=False), "linf"),
         (RandomDropout(0.6), "frobenius"),
-        (RankK(fraction=0.15), "frobenius"),
-        (WithNatural(TopK(0.15)), "frobenius")]:
+        (RankK(fraction=0.15, use_pallas=False), "frobenius"),
+        (WithNatural(TopK(0.15), use_pallas=False), "frobenius")]:
     a = empirical_alpha(comp, key, x, n_trials=4, norm_kind=kind)
     b = comp.payload_bytes(x.shape, jnp.bfloat16)
     print(f"{comp.name:22s} {kind:10s} {a:9.3f} {b:8d}")

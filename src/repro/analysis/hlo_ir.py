@@ -30,8 +30,11 @@ COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
                "collective-permute")
 
 COMP_HEADER_RE = re.compile(r"^(?:ENTRY\s+)?(%?[\w.\-]+)\s*(\(.*)?\{\s*$")
+# the last type alternative takes an array whose layout holds parentheses,
+# as TPU tiled layouts do: u8[4,1,N]{2,1,0:T(4,128)(4,1)}
 INSTR_RE = re.compile(
-    r"^\s*(?:ROOT\s+)?(%?[\w.\-]+) = ((?:\([^=]*?\)|[^(=]*?)) ([\w\-]+)\((.*)$")
+    r"^\s*(?:ROOT\s+)?(%?[\w.\-]+) = "
+    r"((?:\([^=]*?\)|[^(=]*?|\w+\[[\d,]*\]\{[^}]*\})) ([\w\-]+)\((.*)$")
 PARAM_RE = re.compile(r"(%?[\w.\-]+):\s*((?:\w+\[[\d,]*\][^,)]*|\([^)]*\)))")
 CALLED_RE = re.compile(r"(?:calls|to_apply|body)=(%?[\w.\-]+)")
 COND_RE = re.compile(r"condition=(%?[\w.\-]+)")

@@ -29,7 +29,7 @@ Included compressors and the norm w.r.t. which they are contractive:
 from __future__ import annotations
 
 import math
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from typing import Any, ClassVar
 
 import jax
@@ -208,6 +208,8 @@ class RankK:
     """
     fraction: float | None = None   # rank = ceil(fraction * min(m, n)) ...
     rank: int | None = None         # ... or a fixed rank
+    # Newton-Schulz kernel or reference, resolved by the caller
+    use_pallas: bool = field(kw_only=True)
 
     @property
     def name(self):
@@ -232,7 +234,8 @@ class RankK:
         q = state["q"].astype(jnp.float32)
         xf = x.astype(jnp.float32)
         p = xf @ q
-        p = newton_schulz(p, steps=5, use_pallas=False)  # orthonormal-ish cols
+        p = newton_schulz(p, steps=5,                 # orthonormal-ish cols
+                          use_pallas=self.use_pallas)
         qn = xf.T @ p
         payload = {"p": p.astype(x.dtype), "q": qn.astype(x.dtype)}
         return payload, {"q": qn.astype(state["q"].dtype)}
@@ -280,17 +283,19 @@ class Natural:
     Elementwise relative error <= 1/3 => contractive with alpha = 8/9
     w.r.t. every absolute norm (Euclidean, l_inf, l1, Frobenius...)."""
     name: str = "natural"
+    # encode/bit-pack kernels or references, resolved by the caller
+    use_pallas: bool = field(kw_only=True)
 
     def init(self, key, shape, dtype) -> State:
         return {}
 
     def compress(self, state, x):
-        codes, signs = natural_compress(x, use_pallas=False)
+        codes, signs = natural_compress(x, use_pallas=self.use_pallas)
         return {"codes": codes, "signs": signs}, state
 
     def decompress(self, payload, shape, dtype):
         return natural_decompress(payload["codes"], payload["signs"],
-                                  shape, dtype)
+                                  shape, dtype, use_pallas=self.use_pallas)
 
     def payload_bytes(self, shape, dtype) -> int:
         n = _nelem(shape)
@@ -312,6 +317,8 @@ class WithNatural:
     regardless of the inner compressor (``lossless_wire = False``).
     """
     inner: Any
+    # encode/bit-pack kernels or references, resolved by the caller
+    use_pallas: bool = field(kw_only=True)
     lossless_wire: ClassVar[bool] = False
 
     @property
@@ -338,11 +345,13 @@ class WithNatural:
     def compress(self, state, x):
         payload, state = self.inner.compress(state, x)
         if isinstance(self.inner, Identity):
-            codes, signs = natural_compress(payload, use_pallas=False)
+            codes, signs = natural_compress(payload,
+                                            use_pallas=self.use_pallas)
             return {"codes": codes, "signs": signs}, state
         out = dict(payload)
         for name in self._float_leaf_shapes(x.shape):
-            codes, signs = natural_compress(payload[name], use_pallas=False)
+            codes, signs = natural_compress(payload[name],
+                                            use_pallas=self.use_pallas)
             out[name + "_codes"] = codes
             out[name + "_signs"] = signs
             del out[name]
@@ -351,13 +360,13 @@ class WithNatural:
     def decompress(self, payload, shape, dtype):
         if isinstance(self.inner, Identity):
             return self.inner.decompress(natural_decompress(
-                payload["codes"], payload["signs"], shape, jnp.bfloat16),
-                shape, dtype)
+                payload["codes"], payload["signs"], shape, jnp.bfloat16,
+                use_pallas=self.use_pallas), shape, dtype)
         inner_payload = dict(payload)
         for name, lshape in self._float_leaf_shapes(shape).items():
             inner_payload[name] = natural_decompress(
                 payload[name + "_codes"], payload[name + "_signs"],
-                lshape, jnp.bfloat16)
+                lshape, jnp.bfloat16, use_pallas=self.use_pallas)
             del inner_payload[name + "_codes"]
             del inner_payload[name + "_signs"]
         return self.inner.decompress(inner_payload, shape, dtype)
@@ -396,26 +405,32 @@ def empirical_alpha(comp, key, x, n_trials: int = 8, norm_kind: str = "frobenius
     return 1.0 - num / (n_trials * den)
 
 
+# name -> factory(use_pallas): the kernel choice is handed to every
+# compressor that has kernels (Natural, RankK and their WithNatural)
 REGISTRY = {
-    "identity": lambda: Identity(),
-    "natural": lambda: Natural(),
-    "identity+natural": lambda: WithNatural(Identity()),
-    "top5": lambda: TopK(0.05),
-    "top10": lambda: TopK(0.10),
-    "top15": lambda: TopK(0.15),
-    "top20": lambda: TopK(0.20),
-    "top10+natural": lambda: WithNatural(TopK(0.10)),
-    "top15+natural": lambda: WithNatural(TopK(0.15)),
-    "rank5": lambda: RankK(fraction=0.05),
-    "rank10": lambda: RankK(fraction=0.10),
-    "rank15": lambda: RankK(fraction=0.15),
-    "rank20": lambda: RankK(fraction=0.20),
-    "rank10+natural": lambda: WithNatural(RankK(fraction=0.10)),
-    "rank15+natural": lambda: WithNatural(RankK(fraction=0.15)),
+    "identity": lambda up: Identity(),
+    "natural": lambda up: Natural(use_pallas=up),
+    "identity+natural": lambda up: WithNatural(Identity(), use_pallas=up),
+    "top5": lambda up: TopK(0.05),
+    "top10": lambda up: TopK(0.10),
+    "top15": lambda up: TopK(0.15),
+    "top20": lambda up: TopK(0.20),
+    "top10+natural": lambda up: WithNatural(TopK(0.10), use_pallas=up),
+    "top15+natural": lambda up: WithNatural(TopK(0.15), use_pallas=up),
+    "rank5": lambda up: RankK(fraction=0.05, use_pallas=up),
+    "rank10": lambda up: RankK(fraction=0.10, use_pallas=up),
+    "rank15": lambda up: RankK(fraction=0.15, use_pallas=up),
+    "rank20": lambda up: RankK(fraction=0.20, use_pallas=up),
+    "rank10+natural": lambda up: WithNatural(
+        RankK(fraction=0.10, use_pallas=up), use_pallas=up),
+    "rank15+natural": lambda up: WithNatural(
+        RankK(fraction=0.15, use_pallas=up), use_pallas=up),
 }
 
 
-def get_compressor(name: str):
+def get_compressor(name: str, use_pallas: bool):
+    """The registered compressor ``name`` with its kernel choice (a
+    resolved bool: the Pallas kernels or the jnp references)."""
     if name not in REGISTRY:
         raise KeyError(f"unknown compressor '{name}'; have {sorted(REGISTRY)}")
-    return REGISTRY[name]()
+    return REGISTRY[name](use_pallas)

@@ -29,6 +29,7 @@ Special cases recovered exactly (tested):
 """
 from __future__ import annotations
 
+import dataclasses
 from collections import OrderedDict
 from dataclasses import dataclass
 from functools import partial
@@ -44,6 +45,8 @@ from repro.dist.participation import (mask_bcast, participation_mask,
 from repro.dist.pipeline import s2w_issue_order
 from repro.dist.resync import (init_resync_state, replay_masks,
                                resolve_ring_depth, ring_push)
+from repro.dist.sharding import worker_axis_for
+from repro.kernels import resolve_use_pallas
 from repro.obs.metrics import (MetricSet, leaf_names, orth_residual,
                                rel_error, worker_mean_norm)
 from repro.obs.trace import PHASE_SPANS, phase_span, wire_stage_span
@@ -210,7 +213,10 @@ def resolve_stage_plan(cfg: EF21MuonConfig, plan, mesh=None,
 
 class EF21Muon:
     def __init__(self, cfg: EF21MuonConfig):
-        self.cfg = cfg
+        # one kernel choice for the whole step: NS LMO, compressors and
+        # wire codecs all read this resolved bool (kernels.ops)
+        self.cfg = dataclasses.replace(
+            cfg, use_pallas=resolve_use_pallas(cfg.use_pallas))
         self._plans: OrderedDict = OrderedDict()
 
     # ------------------------------------------------------------------ plan
@@ -231,7 +237,8 @@ class EF21Muon:
             if len(self._plans) >= 8:
                 self._plans.popitem(last=False)
             self._plans[key] = LayerPlan.build(
-                params, metas, w2s=self.cfg.w2s, s2w=self.cfg.s2w)
+                params, metas, w2s=self.cfg.w2s, s2w=self.cfg.s2w,
+                use_pallas=self.cfg.use_pallas)
         return self._plans[key]
 
     # ------------------------------------------------------------------ init
@@ -410,6 +417,28 @@ class EF21Muon:
         if reshard_updates is None:
             reshard_updates = lambda tree: tree
 
+        # Mosaic kernels cannot be partitioned automatically. On a mesh of
+        # several devices the kernel-bearing pieces of the step (compress,
+        # pack, unpack, decompress) therefore run per device under
+        # shard_map: worker-side pieces on their worker's shard of the
+        # worker axis, server-side pieces on each device's replicated
+        # copy. That is a value identity and adds no u8 collective: the
+        # (4, 1) nanogpt step compiled for a v5e 2x2 holds the same u8
+        # all-gathers with and without it. The jnp path stays with the
+        # partitioner, lowering as before.
+        kernel_mesh = (mesh if cfg.use_pallas
+                       and isinstance(mesh, jax.sharding.Mesh)
+                       and mesh.size > 1 else None)
+        wspec = (jax.sharding.PartitionSpec(worker_axis_for(mesh))
+                 if kernel_mesh is not None else None)
+
+        def per_device(fn, spec=jax.sharding.PartitionSpec()):
+            if kernel_mesh is None:
+                return fn
+            return lambda *args: jax.shard_map(
+                fn, mesh=kernel_mesh, in_specs=(spec,) * len(args),
+                out_specs=spec, check_vma=False)(*args)
+
         def step(state: dict, grad_and_loss: Callable, batch: Any,
                  t: jax.Array | float) -> tuple[dict, dict]:
             plan = self.plan(state["x"], metas)
@@ -464,10 +493,11 @@ class EF21Muon:
                     cs_f = plan.flatten(state["cs_state"])
                     w_f = plan.flatten(state["w"])
                     x_f0 = plan.flatten(state["x"])
-                    s_payloads, cs_l = _unzip(plan.map_flat(
-                        lambda lp, cs, w, x: ef_compress_step(
-                            lp.s2w, cs, w, x, cfg.wire_dtype)[:2],
-                        cs_f, w_f, x_f0), 2)
+                    s_payloads, cs_l = per_device(
+                        lambda *fl: _unzip(plan.map_flat(
+                            lambda lp, cs, w, x: ef_compress_step(
+                                lp.s2w, cs, w, x, cfg.wire_dtype)[:2],
+                            *fl), 2))(cs_f, w_f, x_f0)
                     # lead dim 1: the server's single broadcast message
                     lead = [jax.tree.map(lambda a: a[None], p)
                             for p in s_payloads]
@@ -484,9 +514,9 @@ class EF21Muon:
 
                     def s2w_apply(i, pl):
                         lp = plan.leaves[i]
-                        return vmap_n(
+                        return per_device(vmap_n(
                             lambda q, w: apply_payload(lp.s2w, q, w),
-                            lp.meta.stack_dims)(
+                            lp.meta.stack_dims))(
                                 jax.tree.map(lambda a: a[0], pl), w_f[i])
 
                     w_l: list = [None] * len(plan.leaves)
@@ -500,8 +530,8 @@ class EF21Muon:
                         for k in order:
                             with phase_span(wire_stage_span("s2w", k),
                                             gspan):
-                                sbufs[k] = broadcast(
-                                    swire.pack_stage(k, lead))
+                                sbufs[k] = broadcast(per_device(
+                                    partial(swire.pack_stage, k))(lead))
                         if resync_on:
                             # §13 replay ring row: this round's gathered
                             # broadcast bytes verbatim, stage sub-
@@ -512,16 +542,19 @@ class EF21Muon:
                         for k in order:
                             for i, pl in zip(
                                     splan.stages[k].leaf_ids,
-                                    swire.unpack_stage(k, sbufs[k])):
+                                    per_device(partial(
+                                        swire.unpack_stage, k))(
+                                            sbufs[k])):
                                 w_l[i] = s2w_apply(i, pl)
                     else:
                         swire = plan.wire_layout(cfg.wire_dtype,
                                                  direction="s2w")
                         with phase_span(wire_stage_span("s2w", 0), gspan):
-                            buf = broadcast(swire.pack(lead))
+                            buf = broadcast(per_device(swire.pack)(lead))
                         if resync_on:
                             ring_row = buf[0]
-                        for i, pl in enumerate(swire.unpack(buf)):
+                        for i, pl in enumerate(
+                                per_device(swire.unpack)(buf)):
                             w_l[i] = s2w_apply(i, pl)
                     w_tree = plan.unflatten(w_l)
                     cs_tree = plan.unflatten(cs_l)
@@ -576,8 +609,9 @@ class EF21Muon:
                                     row, offs[k], offs[k + 1])
                                 for i, pl in zip(
                                         splan.stages[k].leaf_ids,
-                                        rswire.unpack_stage(
-                                            k, seg[None])):
+                                        per_device(partial(
+                                            rswire.unpack_stage, k))(
+                                                seg[None])):
                                     pls[i] = pl
                             return pls
                     else:
@@ -585,7 +619,7 @@ class EF21Muon:
                                                   direction="s2w")
 
                         def unpack_row(row):
-                            return rswire.unpack(row[None])
+                            return per_device(rswire.unpack)(row[None])
 
                     slot_pls = [unpack_row(ring_new[r])
                                 for r in range(ring_depth)]
@@ -594,10 +628,10 @@ class EF21Muon:
                     def rejoin_leaf(i, w):
                         lp = plan.leaves[i]
                         for r in range(ring_depth):
-                            delta = vmap_n(
+                            delta = per_device(vmap_n(
                                 lambda q, c=lp.s2w, s=lp.slice_shape:
                                 c.decompress(q, s, jnp.float32),
-                                lp.meta.stack_dims)(
+                                lp.meta.stack_dims))(
                                     jax.tree.map(lambda a: a[0],
                                                  slot_pls[r][i]))
                             w = jnp.where(
@@ -651,12 +685,13 @@ class EF21Muon:
                         lambda g: g.astype(cfg.state_dtype), grads)
 
                 gw_old = plan.flatten(state["g_w"])
-                payloads, cw_l, gw_l = _unzip(plan.map_flat(
-                    lambda lp, cw, gw, m: ef_compress_step(
-                        lp.w2s, cw, gw, m, cfg.wire_dtype),
-                    plan.flatten(state["cw_state"]),
-                    gw_old,
-                    plan.flatten(m_new), extra_vmap=1), 3)
+                payloads, cw_l, gw_l = per_device(
+                    lambda *fl: _unzip(plan.map_flat(
+                        lambda lp, cw, gw, m: ef_compress_step(
+                            lp.w2s, cw, gw, m, cfg.wire_dtype),
+                        *fl, extra_vmap=1), 3), wspec)(
+                            plan.flatten(state["cw_state"]), gw_old,
+                            plan.flatten(m_new))
 
             # ---- 4.+5. server receive + layer-wise LMO. Shared per-leaf
             # pieces first: decompress one leaf's gathered payloads, pin
@@ -701,9 +736,9 @@ class EF21Muon:
 
             def recv_leaf(i, pl, gs, part=None):
                 lp = plan.leaves[i]
-                d = vmap_n(lambda s: lp.w2s.decompress(
+                d = per_device(vmap_n(lambda s: lp.w2s.decompress(
                     s, lp.slice_shape, jnp.float32),
-                    lp.meta.stack_dims + 1)(pl)
+                    lp.meta.stack_dims + 1))(pl)
                 if rep is not None:
                     d = jax.lax.with_sharding_constraint(d, rep)
                 if part is not None:
@@ -761,8 +796,9 @@ class EF21Muon:
                 with phase_span(PHASE_SPANS[3], gspan):
                     for k in range(splan.n_stages):
                         with phase_span(wire_stage_span("w2s", k), gspan):
-                            buf = reshard_payloads(
-                                swire.pack_stage(k, payloads))
+                            buf = reshard_payloads(per_device(
+                                partial(swire.pack_stage, k), wspec)(
+                                    payloads))
                             if faults is not None:
                                 buf = faults.inject_wire(
                                     buf, state["step"], k, "w2s")
@@ -782,14 +818,16 @@ class EF21Muon:
                         for k, stage in enumerate(splan.stages):
                             for i, pl in zip(
                                     stage.leaf_ids,
-                                    swire.unpack_stage(k, bufs[k])):
+                                    per_device(partial(
+                                        swire.unpack_stage, k))(bufs[k])):
                                 staged_pl[i] = pl
                         part = resolve_mask(staged_pl)
                 for k, stage in enumerate(splan.stages):
                     with phase_span(PHASE_SPANS[3], gspan):
                         pls = ([staged_pl[i] for i in stage.leaf_ids]
                                if elastic
-                               else swire.unpack_stage(k, bufs[k]))
+                               else per_device(partial(
+                                   swire.unpack_stage, k))(bufs[k]))
                         for i, pl in zip(stage.leaf_ids, pls):
                             gs_l[i] = recv_leaf(i, pl, gsrv_l[i], part)
                     with phase_span(PHASE_SPANS[4], gspan):
@@ -811,11 +849,12 @@ class EF21Muon:
                     if pack_wire:
                         wire = plan.wire_layout(cfg.wire_dtype)
                         with phase_span(wire_stage_span("w2s", 0), gspan):
-                            buf = reshard_payloads(wire.pack(payloads))
+                            buf = reshard_payloads(
+                                per_device(wire.pack, wspec)(payloads))
                             if faults is not None:
                                 buf = faults.inject_wire(
                                     buf, state["step"], 0, "w2s")
-                        payloads = wire.unpack(buf)
+                        payloads = per_device(wire.unpack)(buf)
                     else:
                         payloads = reshard_payloads(payloads)
                     part = resolve_mask(payloads) if elastic else None
@@ -838,7 +877,8 @@ class EF21Muon:
                         for bi, b in enumerate(buckets):
                             lmo_bucket(bi, b, gs_l, x_flat, x_l)
                     else:
-                        x_l = plan.map_flat(lmo_leaf, x_flat, gs_l)
+                        x_l = per_device(partial(plan.map_flat, lmo_leaf))(
+                            x_flat, gs_l)
 
             if elastic:
                 # ---- §11 commit: absent/demoted workers' EF21 error

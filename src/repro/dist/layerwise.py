@@ -7,7 +7,8 @@ its stack dims, vmap a per-slice function". A ``LayerPlan`` precomputes
 all of that once per (treedef, metas, shapes) so the optimizer states
 algorithm steps instead of tree mechanics:
 
-    plan = LayerPlan.build(params, metas, w2s="rank10", s2w="natural")
+    plan = LayerPlan.build(params, metas, w2s="rank10", s2w="natural",
+                           use_pallas=False)
     new_x = plan.map_leaves(lmo_leaf, x_tree, g_tree)          # stack-vmapped
     outs  = plan.map_flat(ef_leaf, cw_l, gw_l, m_l, extra_vmap=1)  # + worker dim
 
@@ -39,20 +40,21 @@ def vmap_n(fn: Callable, n: int) -> Callable:
     return fn
 
 
-def resolve_compressor(name: str, slice_shape: tuple[int, ...]):
+def resolve_compressor(name: str, slice_shape: tuple[int, ...],
+                       use_pallas: bool):
     """Instantiate the compressor for one leaf slice (see module
-    docstring for the non-2D fallback rule)."""
+    docstring for the non-2D fallback rule), with its kernel choice."""
     # Deferred import: repro.core.muon (pulled in by repro.core.__init__)
     # imports this module, so a top-level core import would be circular.
     from repro.core import compressors as comp_lib
 
-    comp = comp_lib.get_compressor(name)
+    comp = comp_lib.get_compressor(name, use_pallas)
     inner = comp.inner if isinstance(comp, comp_lib.WithNatural) else comp
     if isinstance(inner, (comp_lib.RankK, comp_lib.TopKSVD)) \
             and len(slice_shape) != 2:
         fallback = comp_lib.TopK(0.25)
         if isinstance(comp, comp_lib.WithNatural):
-            return comp_lib.WithNatural(fallback)
+            return comp_lib.WithNatural(fallback, use_pallas=use_pallas)
         return fallback
     return comp
 
@@ -72,9 +74,10 @@ class LeafPlan:
 class LayerPlan:
     """Per-(treedef, metas, shapes) plan shared by every optimizer phase."""
 
-    def __init__(self, treedef, leaves: list[LeafPlan]):
+    def __init__(self, treedef, leaves: list[LeafPlan], use_pallas: bool):
         self.treedef = treedef
         self.leaves = leaves
+        self.use_pallas = use_pallas    # kernel choice of the wire codecs
         self._wire_layouts: dict = {}   # (dtype name, direction) -> WireLayout
         self._ns_buckets: dict = {}     # (mesh key, fsdp) -> tuple[NSBucket]
         self._stage_plans: dict = {}    # (mesh key, fsdp, stages) -> StagePlan
@@ -83,11 +86,14 @@ class LayerPlan:
 
     @classmethod
     def build(cls, params: Any, metas: Any, w2s: str = "identity",
-              s2w: str = "identity") -> "LayerPlan":
+              s2w: str = "identity", *,
+              use_pallas: bool) -> "LayerPlan":
         """``params`` may be concrete arrays, ShapeDtypeStructs or
         tracers — only ``.shape`` is read. ``metas`` mirrors the params
         tree with ParamMeta leaves; incompressible leaves get identity
-        compressors in both directions."""
+        compressors in both directions. ``use_pallas`` is the resolved
+        kernel choice (``EF21Muon`` resolves it once), handed to every
+        compressor and wire codec of the plan."""
         with span("plan/build"):
             leaves, treedef = jax.tree.flatten(params)
             metas_l = treedef.flatten_up_to(metas)
@@ -101,9 +107,9 @@ class LayerPlan:
                 plans.append(LeafPlan(
                     meta=m, shape=shape, stack_shape=stack, slice_shape=sshape,
                     n_stack=int(math.prod(stack)) if stack else 1,
-                    w2s=resolve_compressor(wname, sshape),
-                    s2w=resolve_compressor(sname, sshape)))
-            return cls(treedef, plans)
+                    w2s=resolve_compressor(wname, sshape, use_pallas),
+                    s2w=resolve_compressor(sname, sshape, use_pallas)))
+            return cls(treedef, plans, use_pallas)
 
     # ------------------------------------------------------------- tree ops
     def flatten(self, tree: Any) -> list:

@@ -9,10 +9,11 @@ from .bitpack import (narrow_decode, narrow_encode, narrow_width, pack_bits,
                       unpack_bits)
 from .newton_schulz import fused_ns_feasible
 from .ops import (NS_COEFFS, count_ns_dispatches, natural_compress,
-                  natural_decompress, newton_schulz, newton_schulz_batched)
+                  natural_decompress, newton_schulz, newton_schulz_batched,
+                  resolve_use_pallas)
 
 __all__ = ["NS_COEFFS", "natural_compress", "natural_decompress",
            "newton_schulz", "newton_schulz_batched", "fused_ns_feasible",
-           "count_ns_dispatches",
+           "count_ns_dispatches", "resolve_use_pallas",
            "pack_bits", "unpack_bits", "narrow_encode", "narrow_decode",
            "narrow_width"]

@@ -13,12 +13,17 @@ both the CPU execution path and the interpret-mode oracle:
     then the next plane(s) — each plane is a contiguous lane-aligned
     array, which keeps the TPU kernels pure VPU shift/mask ops.
 
+Each takes ``use_pallas`` as a resolved bool (``ops.resolve_use_pallas``):
+the kernel or its reference, never a choice of its own.
+
 Kernel notes (TPU adaptation):
   * the 1-bit kernels are lane-dim reductions/expansions by 8; both are
     expressed as one [1024, 128]-tiled matmul against a constant
     selector matrix built from iota (bit values <= 255 and power-of-two
     weights are exactly representable, and the dot runs with HIGHEST
-    precision, so the arithmetic is exact).
+    precision, so the arithmetic is exact). uint8 is widened through
+    int32 on both sides of the float math: Mosaic has no direct
+    uint8 <-> float32 cast.
   * the narrow kernels never touch the MXU: plane-major layout makes
     encode a shifted mask per grid step and decode a shift-accumulate
     over the plane grid dimension (int32 VPU ops; exact by
@@ -34,10 +39,6 @@ from jax.experimental import pallas as pl
 
 _LANES = 128
 _BITS_IN = _LANES * 8  # input lanes per packed 128-lane output tile
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 # ---------------------------------------------------------------- jnp refs
@@ -79,26 +80,25 @@ def _pack_bits_kernel(b_ref, o_ref):
     l = jax.lax.broadcasted_iota(jnp.int32, (_BITS_IN, _LANES), 0)
     t = jax.lax.broadcasted_iota(jnp.int32, (_BITS_IN, _LANES), 1)
     w = jnp.where(l // 8 == t, jnp.exp2((l % 8).astype(jnp.float32)), 0.0)
-    acc = jnp.dot(b_ref[...].astype(jnp.float32), w,
+    acc = jnp.dot(b_ref[...].astype(jnp.int32).astype(jnp.float32), w,
                   preferred_element_type=jnp.float32,
                   precision=jax.lax.Precision.HIGHEST)
-    o_ref[...] = acc.astype(jnp.uint8)
+    o_ref[...] = acc.astype(jnp.int32).astype(jnp.uint8)
 
 
 def _unpack_bits_kernel(p_ref, o_ref):
     # [bm, 128] bytes -> [bm, 1024] bits: replicate each byte over its 8
     # bit lanes (dot with a 0/1 selector), then extract bit (l % 8) with
-    # exact f32 floor/mod arithmetic (bytes <= 255).
+    # int32 shift/mask (bytes <= 255 are exact in f32).
     t = jax.lax.broadcasted_iota(jnp.int32, (_LANES, _BITS_IN), 0)
     l = jax.lax.broadcasted_iota(jnp.int32, (_LANES, _BITS_IN), 1)
-    rep = jnp.dot(p_ref[...].astype(jnp.float32),
+    rep = jnp.dot(p_ref[...].astype(jnp.int32).astype(jnp.float32),
                   jnp.where(l // 8 == t, 1.0, 0.0),
                   preferred_element_type=jnp.float32,
                   precision=jax.lax.Precision.HIGHEST)
-    shift = jnp.exp2((jax.lax.broadcasted_iota(jnp.int32, (1, _BITS_IN), 1)
-                      % 8).astype(jnp.float32))
-    quot = jnp.floor(rep / shift)
-    o_ref[...] = (quot - 2.0 * jnp.floor(quot / 2.0)).astype(jnp.uint8)
+    shift = jax.lax.broadcasted_iota(jnp.int32, (1, _BITS_IN), 1) % 8
+    bit = (rep.astype(jnp.int32) >> shift) & 1
+    o_ref[...] = bit.astype(jnp.uint8)
 
 
 def _rows_2d(flat: jax.Array, lanes: int,
@@ -115,19 +115,18 @@ def _rows_2d(flat: jax.Array, lanes: int,
     return x, block
 
 
-def pack_bits(bits01: jax.Array, use_pallas: str | bool = "auto",
+def pack_bits(bits01: jax.Array, *, use_pallas: bool,
               interpret: bool = False) -> jax.Array:
     """[8k] uint8 of {0,1} -> [k] uint8, LSB first (bit-exact pair with
     ``unpack_bits``; layout identical to the historical ops.py packer)."""
     n = bits01.shape[0]
     assert n % 8 == 0, n
-    if use_pallas == "auto":
-        use_pallas = _on_tpu()
     if not use_pallas:
         return pack_bits_ref(bits01)
     x, block = _rows_2d(bits01, _BITS_IN)
     out = pl.pallas_call(
         _pack_bits_kernel,
+        name="pack_bits",
         grid=(x.shape[0] // block,),
         in_specs=[pl.BlockSpec((block, _BITS_IN), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((block, _LANES), lambda i: (i, 0)),
@@ -137,17 +136,16 @@ def pack_bits(bits01: jax.Array, use_pallas: str | bool = "auto",
     return out.reshape(-1)[:n // 8]
 
 
-def unpack_bits(packed: jax.Array, use_pallas: str | bool = "auto",
+def unpack_bits(packed: jax.Array, *, use_pallas: bool,
                 interpret: bool = False) -> jax.Array:
     """[k] uint8 -> [8k] uint8 of {0,1} (inverse of ``pack_bits``)."""
     k = packed.shape[0]
-    if use_pallas == "auto":
-        use_pallas = _on_tpu()
     if not use_pallas:
         return unpack_bits_ref(packed)
     x, block = _rows_2d(packed, _LANES)
     out = pl.pallas_call(
         _unpack_bits_kernel,
+        name="unpack_bits",
         grid=(x.shape[0] // block,),
         in_specs=[pl.BlockSpec((block, _LANES), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((block, _BITS_IN), lambda i: (i, 0)),
@@ -186,21 +184,20 @@ def narrow_width(domain: int) -> int:
 
 
 def narrow_encode(idx: jax.Array, width: int,
-                  use_pallas: str | bool = "auto",
+                  *, use_pallas: bool,
                   interpret: bool = False) -> jax.Array:
     """int32 [k] -> uint8 [width*k], plane-major little-endian.
 
     Values must lie in [0, 2^(8*width)); bit-exact pair with
     ``narrow_decode``. width == 4 round-trips any non-negative int32."""
     k = idx.shape[0]
-    if use_pallas == "auto":
-        use_pallas = _on_tpu()
     if not use_pallas:
         return narrow_encode_ref(idx, width)
     x, block = _rows_2d(idx, _LANES)
     rows = x.shape[0]
     out = pl.pallas_call(
         _narrow_encode_kernel,
+        name="narrow_encode",
         grid=(width, rows // block),
         in_specs=[pl.BlockSpec((1, block, _LANES), lambda j, i: (0, i, 0))],
         out_specs=pl.BlockSpec((1, block, _LANES), lambda j, i: (j, i, 0)),
@@ -212,13 +209,11 @@ def narrow_encode(idx: jax.Array, width: int,
 
 
 def narrow_decode(b: jax.Array, width: int,
-                  use_pallas: str | bool = "auto",
+                  *, use_pallas: bool,
                   interpret: bool = False) -> jax.Array:
     """uint8 [width*k] plane-major -> int32 [k]."""
     assert b.shape[0] % width == 0, (b.shape, width)
     k = b.shape[0] // width
-    if use_pallas == "auto":
-        use_pallas = _on_tpu()
     if not use_pallas:
         return narrow_decode_ref(b, width)
     pad = (-k) % _LANES
@@ -231,6 +226,7 @@ def narrow_decode(b: jax.Array, width: int,
         planes = jnp.pad(planes, ((0, 0), (0, rpad), (0, 0)))
     out = pl.pallas_call(
         functools.partial(_narrow_decode_kernel, width=width),
+        name="narrow_decode",
         grid=(planes.shape[1] // block, width),
         in_specs=[pl.BlockSpec((1, block, _LANES), lambda i, j: (j, i, 0))],
         out_specs=pl.BlockSpec((block, _LANES), lambda i, j: (i, 0)),

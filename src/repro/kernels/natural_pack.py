@@ -14,15 +14,20 @@ from jax.experimental import pallas as pl
 
 
 def _natural_encode_kernel(x_ref, code_ref, sign_ref):
-    bits = jax.lax.bitcast_convert_type(x_ref[...].astype(jnp.bfloat16),
-                                        jnp.uint16)
-    sign = (bits >> 15).astype(jnp.uint8)
-    exp = ((bits >> 7) & 0xFF).astype(jnp.uint16)
-    mant_hi = (bits >> 6) & 0x1
+    # Round to bf16 (the wire format is defined on bf16), widen exactly to
+    # f32 and do the bit work in int32: the TPU vector unit has no 16-bit
+    # shifts. bf16 sign/exponent/top-mantissa bits 15/14..7/6 are f32 bits
+    # 31/30..23/22, and the low 16 f32 bits are zero, so the codes are the
+    # same as ref.natural_compress_ref's.
+    xf = x_ref[...].astype(jnp.bfloat16).astype(jnp.float32)
+    bits = jax.lax.bitcast_convert_type(xf, jnp.int32)
+    sign = (bits >> 31) & 0x1
+    exp = (bits >> 23) & 0xFF
+    mant_hi = (bits >> 22) & 0x1
     exp_rounded = jnp.minimum(exp + mant_hi, 254)
-    is_zero = (bits & 0x7FFF) == 0
-    code_ref[...] = jnp.where(is_zero, jnp.uint16(0), exp_rounded).astype(jnp.uint8)
-    sign_ref[...] = sign
+    is_zero = (bits & 0x7FFFFFFF) == 0
+    code_ref[...] = jnp.where(is_zero, 0, exp_rounded).astype(jnp.uint8)
+    sign_ref[...] = sign.astype(jnp.uint8)
 
 
 def natural_encode(x: jax.Array, *, block_rows: int = 256,
@@ -37,6 +42,7 @@ def natural_encode(x: jax.Array, *, block_rows: int = 256,
     spec = pl.BlockSpec((block_rows, cols), lambda i: (i, 0))
     return pl.pallas_call(
         _natural_encode_kernel,
+        name="natural_encode",
         grid=grid,
         in_specs=[spec],
         out_specs=(spec, spec),

@@ -43,10 +43,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# renamed across jax versions: TPUCompilerParams (<=0.4.x) -> CompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 
 def _fused_matmul_kernel(a_ref, b_ref, c_ref, o_ref, acc_ref, *, nk: int,
                          alpha: float, beta: float, has_c: bool):
@@ -78,12 +74,6 @@ def fused_matmul(a: jax.Array, b: jax.Array, c: jax.Array | None = None,
     assert k == k2, (a.shape, b.shape)
     assert m % block_m == 0 and n % block_n == 0 and k % block_k == 0, \
         (a.shape, b.shape, block_m, block_n, block_k)
-    if _CompilerParams is None:
-        raise RuntimeError(
-            "jax.experimental.pallas.tpu exposes neither CompilerParams nor "
-            "TPUCompilerParams in this jax version; the Pallas "
-            "Newton-Schulz path cannot be configured — pass "
-            "use_pallas=False (jnp reference) or update jax.")
     out_dtype = out_dtype or a.dtype
     nk = k // block_k
     has_c = c is not None
@@ -103,12 +93,13 @@ def fused_matmul(a: jax.Array, b: jax.Array, c: jax.Array | None = None,
                                beta=beta, has_c=has_c)
     return pl.pallas_call(
         kernel,
+        name="fused_matmul",
         grid=(m // block_m, n // block_n, nk),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -225,12 +216,6 @@ def ns_iteration_fused(x: jax.Array, coeffs, *, block_m: int = 128,
         raise ValueError(
             f"ns_iteration_fused needs block-aligned slices, got {x.shape} "
             f"for blocks ({block_m}, {block_n}) — pad upstream (ops.py)")
-    if _CompilerParams is None:
-        raise RuntimeError(
-            "jax.experimental.pallas.tpu exposes neither CompilerParams nor "
-            "TPUCompilerParams in this jax version; the Pallas "
-            "Newton-Schulz path cannot be configured — pass "
-            "use_pallas=False (jnp reference) or update jax.")
     a, b, c = coeffs
     nj = n // block_n
     kernel = functools.partial(
@@ -239,13 +224,14 @@ def ns_iteration_fused(x: jax.Array, coeffs, *, block_m: int = 128,
     spec = pl.BlockSpec((1, m, block_n), lambda bi, ph, j: (bi, 0, j))
     return pl.pallas_call(
         kernel,
+        name="ns_iteration_fused",
         grid=(bsz, 2, nj),
         in_specs=[spec],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((bsz, m, n), x.dtype),
         scratch_shapes=[pltpu.VMEM((m, m), jnp.float32),
                         pltpu.VMEM((m, m), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,

@@ -19,11 +19,19 @@ from .newton_schulz import (fused_ns_feasible, ns_iteration_fused,
 NS_COEFFS = ref.NS_COEFFS
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def resolve_use_pallas(use_pallas: str | bool) -> bool:
+    """The one kernel-selection rule of this package: ``"auto"`` means
+    the Pallas kernels on a TPU backend and the jnp references everywhere
+    else. ``EF21Muon`` resolves it once and passes the bool to every
+    kernel call of the step, so no call on the chip falls back to a
+    reference."""
+    if use_pallas == "auto":
+        return jax.default_backend() == "tpu"
+    return bool(use_pallas)
 
 
-NS_KERNEL_NAMES = ("_ns_fused_kernel", "_fused_matmul_kernel")
+# the stable ``name=`` of every NS pallas_call (kernels/newton_schulz.py)
+NS_KERNEL_NAMES = ("ns_iteration_fused", "fused_matmul")
 
 
 def count_ns_dispatches(jaxpr, names=NS_KERNEL_NAMES) -> int:
@@ -35,11 +43,9 @@ def count_ns_dispatches(jaxpr, names=NS_KERNEL_NAMES) -> int:
 
     n = 0
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            kname = getattr(eqn.params.get("name_and_src_info"), "name",
-                            None) or str(eqn.params.get("name", ""))
-            if any(s in kname for s in names):
-                n += 1
+        if eqn.primitive.name == "pallas_call" \
+                and eqn.params.get("name") in names:
+            n += 1
         for v in eqn.params.values():
             vs = v if isinstance(v, (tuple, list)) else (v,)
             for vi in vs:                 # lax.cond/switch keep a tuple
@@ -76,9 +82,7 @@ def newton_schulz(g: jax.Array, steps: int = 5, coeffs=NS_COEFFS,
     """
     if g.ndim != 2:
         raise ValueError("newton_schulz expects 2-D input")
-    if use_pallas == "auto":
-        use_pallas = _on_tpu()
-    if not use_pallas:
+    if not resolve_use_pallas(use_pallas):
         return ref.newton_schulz_ref(g, steps=steps, coeffs=coeffs, eps=eps)
     transpose = g.shape[0] > g.shape[1]
     x = g.T if transpose else g
@@ -126,9 +130,7 @@ def newton_schulz_batched(g: jax.Array, steps: int = 5, coeffs=NS_COEFFS,
     """
     if g.ndim != 3:
         raise ValueError("newton_schulz_batched expects [B, m, n]")
-    if use_pallas == "auto":
-        use_pallas = _on_tpu()
-    if not use_pallas:
+    if not resolve_use_pallas(use_pallas):
         hook = None
         if mesh is not None and pspec is not None \
                 and isinstance(mesh, jax.sharding.Mesh):
@@ -161,25 +163,27 @@ def newton_schulz_batched(g: jax.Array, steps: int = 5, coeffs=NS_COEFFS,
                     s, coeffs, block=block, interpret=interpret))(x)
         return x[:, :m, :n]
 
-    if mesh is not None and pspec is not None \
-            and isinstance(mesh, jax.sharding.Mesh) and len(pspec) \
-            and pspec[0] is not None:
-        from jax.experimental.shard_map import shard_map
+    if isinstance(mesh, jax.sharding.Mesh) and mesh.size > 1:
+        # Mosaic kernels cannot be partitioned automatically: on a mesh
+        # of several devices the chain always runs under shard_map, on
+        # the batch axes of ``pspec`` when they divide the stack, else on
+        # each device's replicated copy. Each shard needs its slices
+        # whole (the fused kernel grams over the full [m, n] slice
+        # locally), so any trailing model spec stays outside the
+        # shard_map — the kernel is batch-parallel, TP applies to the
+        # jnp path.
         from jax.sharding import PartitionSpec as P
 
-        lead = pspec[0]
-        axes = (lead,) if isinstance(lead, str) else tuple(lead)
+        lead = pspec[0] if pspec is not None and len(pspec) else None
+        axes = () if lead is None else (
+            (lead,) if isinstance(lead, str) else tuple(lead))
         shards = 1
         for a in axes:
             shards *= mesh.shape[a]
-        if shards > 1 and g.shape[0] % shards == 0:
-            # batch axes only: each shard needs its slices whole (the
-            # fused kernel grams over the full [m, n] slice locally), so
-            # any trailing model spec stays outside the shard_map — the
-            # kernel is batch-parallel, TP applies to the jnp path.
-            spec = P(lead, None, None)
-            return shard_map(chain, mesh=mesh, in_specs=(spec,),
-                             out_specs=spec, check_rep=False)(g)
+        spec = P(lead, None, None) if shards > 1 \
+            and g.shape[0] % shards == 0 else P()
+        return jax.shard_map(chain, mesh=mesh, in_specs=(spec,),
+                             out_specs=spec, check_vma=False)(g)
     return chain(g)
 
 
@@ -189,8 +193,7 @@ def natural_compress(x: jax.Array, use_pallas: str | bool = "auto",
     uint8 [ceil(N/8)]). The wire payload is 9 bits/value."""
     flat = x.reshape(-1)
     n = flat.shape[0]
-    if use_pallas == "auto":
-        use_pallas = _on_tpu()
+    use_pallas = resolve_use_pallas(use_pallas)
     if use_pallas:
         lanes = 128
         pad = (-n) % (lanes * 8)
@@ -217,7 +220,8 @@ def natural_decompress(code: jax.Array, packed_sign: jax.Array,
                        use_pallas: str | bool = "auto",
                        interpret: bool = False) -> jax.Array:
     n = code.shape[0]
-    sign = unpack_bits(packed_sign, use_pallas=use_pallas,
+    sign = unpack_bits(packed_sign,
+                       use_pallas=resolve_use_pallas(use_pallas),
                        interpret=interpret)[:n]
     vals = ref.natural_decompress_ref(code, sign)
     return vals.reshape(shape).astype(dtype)

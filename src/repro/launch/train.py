@@ -1,10 +1,13 @@
-"""Training CLI (CPU-scale real runs; the dry-run exercises full scale).
+"""Training CLI.
 
     PYTHONPATH=src python -m repro.launch.train --arch granite-3-2b \
         --reduced --steps 50 --workers 2 --w2s top10 --radius 0.01
 
 Runs the distributed EF21-Muon trainer on the synthetic Zipf-Markov
-pipeline, logs loss + w2s wire bytes, and optionally checkpoints.
+pipeline, logs loss + w2s wire bytes, and optionally checkpoints. It
+takes ``TrainerConfig``'s defaults for kernels and recomputation: the
+Pallas kernels on a TPU and the jnp references elsewhere, with remat on.
+The persistent compilation cache follows ``repro.launch.compile_cache``.
 """
 from __future__ import annotations
 
@@ -18,6 +21,7 @@ from repro.configs import get_config
 from repro.configs.base import ShapeSpec
 from repro.core.schedule import warmup_linear_decay
 from repro.data import SyntheticLM
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.api import build_model
 from repro.obs.sink import MetricsWriter, run_manifest
 from repro.obs.trace import span_summary
@@ -121,6 +125,7 @@ def main():
         print("warning: --supervise needs the input state intact for "
               "retries; disabling --donate")
         args.donate = False
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -132,7 +137,7 @@ def main():
               if args.faults else None)
     tcfg = TrainerConfig(
         n_workers=args.workers, beta=args.beta, w2s=args.w2s, s2w=args.s2w,
-        remat=False, use_pallas=False, metrics=args.metrics_out is not None,
+        metrics=args.metrics_out is not None,
         trace_spans=args.trace_spans, participation=args.participation,
         participation_seed=args.participation_seed, faults=faults,
         donate=args.donate, resync=args.resync)

@@ -19,9 +19,9 @@ are laid out back-to-back in the same buffer region by the WireLayout.
 Codec selection (``leaf_codecs``) is static: it reads the resolved
 compressor and the abstract payload structure, never array values, so a
 ``WireLayout`` is built once per LayerPlan and reused by every traced
-step. On TPU the narrow codecs run the Pallas kernels in
-``kernels/bitpack.py``; on CPU they use the bit-identical jnp references
-(the interpret-mode fallback that keeps tests exact).
+step. The narrow codecs run the Pallas kernels in ``kernels/bitpack.py``
+or their bit-identical jnp references, as the plan's resolved
+``use_pallas`` says (the kernels on a TPU, the references elsewhere).
 """
 from __future__ import annotations
 
@@ -84,6 +84,7 @@ class NarrowIntCodec:
     """int32 indices in [0, 2^(8*width)) as width-byte planes."""
     shape: tuple[int, ...]
     width: int                      # 2 (uint16) or 3 (uint24); 4 = raw
+    use_pallas: bool                # encode/decode kernels or references
 
     @property
     def nbytes(self) -> int:
@@ -95,10 +96,12 @@ class NarrowIntCodec:
 
     def pack(self, x: jax.Array) -> jax.Array:
         assert tuple(x.shape) == tuple(self.shape), (x.shape, self.shape)
-        return narrow_encode(x.astype(jnp.int32).reshape(-1), self.width)
+        return narrow_encode(x.astype(jnp.int32).reshape(-1), self.width,
+                             use_pallas=self.use_pallas)
 
     def unpack(self, b: jax.Array) -> jax.Array:
-        return narrow_decode(b, self.width).reshape(self.shape)
+        return narrow_decode(b, self.width,
+                             use_pallas=self.use_pallas).reshape(self.shape)
 
 
 def index_domains(comp: Any, slice_shape: tuple[int, ...]) -> dict[str, int]:
@@ -113,7 +116,8 @@ def index_domains(comp: Any, slice_shape: tuple[int, ...]) -> dict[str, int]:
 
 
 def leaf_codecs(comp: Any, slice_shape: tuple[int, ...],
-                payload_struct: Any) -> tuple[tuple, Any]:
+                payload_struct: Any,
+                use_pallas: bool) -> tuple[tuple, Any]:
     """(codecs, treedef) for one resolved compressor's per-slice payload.
 
     ``payload_struct`` is the abstract (ShapeDtypeStruct) payload of one
@@ -127,7 +131,8 @@ def leaf_codecs(comp: Any, slice_shape: tuple[int, ...],
         if name in domains and jnp.issubdtype(leaf.dtype, jnp.integer):
             width = narrow_width(domains[name])
             if width < 4:
-                codecs.append(NarrowIntCodec(tuple(leaf.shape), width))
+                codecs.append(NarrowIntCodec(tuple(leaf.shape), width,
+                                             use_pallas))
                 continue
         codecs.append(RawCodec(tuple(leaf.shape), jnp.dtype(leaf.dtype).name))
     return tuple(codecs), treedef

@@ -218,7 +218,8 @@ def build_layout(plan: Any, wire_dtype, direction: str = "w2s") -> WireLayout:
         in_dtype = (jnp.float32 if getattr(comp, "lossless_wire", False)
                     else jnp.dtype(wire_dtype))
         struct = _payload_struct(comp, lp.slice_shape, in_dtype)
-        codecs, treedef = leaf_codecs(comp, lp.slice_shape, struct)
+        codecs, treedef = leaf_codecs(comp, lp.slice_shape, struct,
+                                      plan.use_pallas)
         splits, pos = [], 0
         for c in codecs:
             splits.append(pos)

@@ -50,16 +50,20 @@ def test_hlo_cost_reexports_shared_ir():
     assert hlo_cost.Instr is hlo_ir.Instr
 
 
-def test_parse_handwritten_module():
+# a CPU layout, and a TPU tiled layout whose parentheses the type must take
+@pytest.mark.parametrize("layout", ["{0}", "{0:T(1024)(128)(4,1)}"])
+def test_parse_handwritten_module(layout):
     comps = hlo_ir.parse_module(_module([
-        "p0 = u8[1024]{0} parameter(0)",
-        "ROOT c = u8[1024]{0} copy(p0)",
+        f"p0 = u8[1024]{layout} parameter(0)",
+        f"ag = u8[4096]{layout} all-gather(p0), dimensions={{0}}",
+        f"ROOT c = u8[1024]{layout} copy(p0)",
     ]))
     entry = hlo_ir.entry_name(comps)
     comp = comps[entry]
     assert comp.sizes["p0"] == 1024
+    assert comp.sizes["ag"] == 4096
     assert [hlo_ir.base_op(i.op) for i in comp.instrs] == \
-        ["parameter", "copy"]
+        ["parameter", "all-gather", "copy"]
 
 
 # ------------------------------------------------------- orphan regression

@@ -25,7 +25,7 @@ def _contract_ratio(comp, key, x, kind="frobenius", trials=4):
 
 @pytest.mark.parametrize("name", sorted(C.REGISTRY))
 def test_registry_roundtrip_shapes(name, key):
-    comp = C.get_compressor(name)
+    comp = C.get_compressor(name, use_pallas=False)
     shape = (24, 16)
     x = jax.random.normal(key, shape, jnp.float32)
     state = comp.init(key, shape, jnp.dtype(jnp.bfloat16))
@@ -87,7 +87,7 @@ def test_natural_relative_error_bound(key):
     """|C(x) - x| <= |x| / 3 elementwise => alpha >= 8/9 (§D / Horvath)."""
     x = jax.random.normal(key, (64, 64)) * jnp.exp(
         jax.random.normal(jax.random.fold_in(key, 1), (64, 64)) * 3)
-    comp = C.Natural()
+    comp = C.Natural(use_pallas=False)
     payload, _ = comp.compress({}, x.astype(jnp.bfloat16))
     xh = np.asarray(comp.decompress(payload, x.shape, jnp.float32))
     xb = np.asarray(x.astype(jnp.bfloat16), np.float32)
@@ -98,7 +98,7 @@ def test_natural_relative_error_bound(key):
 
 def test_natural_preserves_powers_of_two(key):
     x = jnp.array([1.0, 2.0, -4.0, 0.5, -0.25, 0.0, 1024.0])
-    comp = C.Natural()
+    comp = C.Natural(use_pallas=False)
     payload, _ = comp.compress({}, x.astype(jnp.bfloat16))
     xh = comp.decompress(payload, x.shape, jnp.float32)
     np.testing.assert_allclose(np.asarray(xh), np.asarray(x))
@@ -119,7 +119,7 @@ def test_dropout_damping_alpha(key):
 def test_rankk_approximately_contractive(key):
     """PowerSGD-style RankK with NS orthonormalisation + warm start:
     contractive in expectation after warm-up (Remark 11)."""
-    comp = C.RankK(fraction=0.25)
+    comp = C.RankK(fraction=0.25, use_pallas=False)
     x = jax.random.normal(key, (32, 24))
     state = comp.init(key, x.shape, jnp.float32)
     # warm-start: iterate on the same matrix; ratio should drop well < 1
@@ -137,10 +137,11 @@ def test_with_natural_combo_bytes(key):
     """TopK+Natural / RankK+Natural payloads: float planes shrink to
     9 bits/value; indices stay int32 (paper Table 2 accounting)."""
     shape = (64, 48)
-    top = C.WithNatural(C.TopK(0.1))
+    top = C.WithNatural(C.TopK(0.1), use_pallas=False)
     k = top.inner.k_for(shape)
     assert top.payload_bytes(shape, jnp.bfloat16) == k * 4 + k + (k + 7) // 8
-    rk = C.WithNatural(C.RankK(fraction=0.1))
+    rk = C.WithNatural(
+        C.RankK(fraction=0.1, use_pallas=False), use_pallas=False)
     r = rk.inner.rank_for(shape)
     nn = (64 + 48) * r
     assert rk.payload_bytes(shape, jnp.bfloat16) == nn + (nn + 7) // 8

@@ -103,7 +103,7 @@ def test_apply_payload_matches_sender_estimate(name, key):
     must be *bit-identical* to the ``new_estimate`` the sender computed in
     ``ef_compress_step`` — the whole point of transmitting C(T - E) is
     that both sides advance E by the exact same decompressed message."""
-    comp = get_compressor(name)
+    comp = get_compressor(name, use_pallas=False)
     shape = (24, 16)
     target = jax.random.normal(key, shape, jnp.float32)
     est_send = jnp.zeros(shape, jnp.float32)
@@ -123,7 +123,7 @@ def test_apply_payload_matches_sender_on_stacked_leaf(name, key):
     the stack dim, exactly as LayerPlan drives the optimizer phases."""
     L, shape = 3, (12, 8)
     target = jax.random.normal(key, (L,) + shape, jnp.float32)
-    comp = get_compressor(name)
+    comp = get_compressor(name, use_pallas=False)
     keys = jax.random.split(key, L)
     state = jax.vmap(
         lambda k: comp.init(k, shape, jnp.dtype(jnp.bfloat16)))(keys)
@@ -150,18 +150,18 @@ def test_rank_fallback_is_deterministic_and_wrapped():
     from repro.core import compressors as C
     from repro.dist.layerwise import resolve_compressor
 
-    assert isinstance(resolve_compressor("rank10", (128,)), C.TopK)
-    fb = resolve_compressor("rank10+natural", (128,))
+    assert isinstance(resolve_compressor("rank10", (128,), False), C.TopK)
+    fb = resolve_compressor("rank10+natural", (128,), False)
     assert isinstance(fb, C.WithNatural) and isinstance(fb.inner, C.TopK)
     # 2-D slices keep exactly what was asked for
-    assert isinstance(resolve_compressor("rank10", (64, 32)), C.RankK)
+    assert isinstance(resolve_compressor("rank10", (64, 32), False), C.RankK)
     # non-rank compressors pass through on any shape
-    assert isinstance(resolve_compressor("top10", (128,)), C.TopK)
-    assert isinstance(resolve_compressor("natural", (128,)), C.Natural)
+    assert isinstance(resolve_compressor("top10", (128,), False), C.TopK)
+    assert isinstance(resolve_compressor("natural", (128,), False), C.Natural)
 
 
 def test_identity_compressor_ef_is_exact(key):
-    comp = get_compressor("identity")
+    comp = get_compressor("identity", use_pallas=False)
     target = jax.random.normal(key, (8, 8))
     est = jnp.zeros_like(target)
     _, _, est = ef_compress_step(comp, {}, est, target, jnp.float32)

@@ -208,19 +208,6 @@ def test_natural_compress_pallas_signs_roundtrip(key):
     np.testing.assert_array_equal(np.asarray(x1), np.asarray(x2))
 
 
-def test_newton_schulz_errors_without_compiler_params(key, monkeypatch):
-    """Neither CompilerParams nor TPUCompilerParams -> an explicit error
-    (not a None crash) on the Pallas path; the jnp path keeps working."""
-    import importlib
-    ns_mod = importlib.import_module("repro.kernels.newton_schulz")
-    g = jax.random.normal(key, (16, 16))
-    monkeypatch.setattr(ns_mod, "_CompilerParams", None)
-    with pytest.raises(RuntimeError, match="CompilerParams"):
-        ns_mod.fused_matmul(jnp.zeros((128, 128)), jnp.zeros((128, 128)))
-    out = newton_schulz(g, steps=2, use_pallas=False)
-    assert out.shape == g.shape
-
-
 def test_ns_zero_padding_exactness(key):
     """Zero padding is exact for NS: padded result sliced back equals the
     unpadded oracle (the ops.py wrapper invariant)."""

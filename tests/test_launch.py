@@ -101,3 +101,24 @@ def test_make_batch_matches_input_specs(key):
     for k in specs:
         assert batch[k].shape == specs[k].shape
         assert batch[k].dtype == specs[k].dtype
+
+
+def test_compile_cache_follows_env_else_checkout(monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR wins untouched; without it the cache is
+    the fixed .jax_cache/ of the checkout."""
+    import jax
+
+    from repro.launch import compile_cache as cc
+
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert cc.enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == prev
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = cc.enable_compile_cache()
+        assert path == str(cc.CHECKOUT / ".jax_cache")
+        assert (cc.CHECKOUT / "pyproject.toml").exists()
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)

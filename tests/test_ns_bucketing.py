@@ -47,7 +47,7 @@ def _tiny_tree(key):
 
 def test_bucket_formation(key):
     params, metas = _tiny_tree(key)
-    plan = LayerPlan.build(params, metas)
+    plan = LayerPlan.build(params, metas, use_pallas=False)
     buckets = plan.ns_buckets()
     assert buckets is plan.ns_buckets()          # memoised
     by_shape = {b.shape: b for b in buckets}
@@ -72,7 +72,7 @@ def test_bucket_formation(key):
 
 def test_stack_unstack_roundtrip_exact(key):
     params, metas = _tiny_tree(key)
-    plan = LayerPlan.build(params, metas)
+    plan = LayerPlan.build(params, metas, use_pallas=False)
     flat = plan.flatten(params)
     for b in plan.ns_buckets():
         stacked = b.stack([flat[i] for i in b.leaf_ids])
@@ -88,7 +88,7 @@ def test_stack_mixed_dtypes_names_leaves(key):
     names the offending leaf_ids (a trace-time phase-5 failure must point
     at leaves, not anonymous parts)."""
     params, metas = _tiny_tree(key)
-    plan = LayerPlan.build(params, metas)
+    plan = LayerPlan.build(params, metas, use_pallas=False)
     b = plan.ns_buckets()[0]
     flat = plan.flatten(params)
     leaves = [flat[i] for i in b.leaf_ids]
@@ -109,7 +109,7 @@ def test_bucket_pspecs_on_mesh(key):
     from test_sharding import FakeMesh
 
     params, metas = _tiny_tree(key)
-    plan = LayerPlan.build(params, metas)
+    plan = LayerPlan.build(params, metas, use_pallas=False)
     mesh = FakeMesh(data=5, model=4)
     buckets = plan.ns_buckets(mesh=mesh)
     assert buckets is plan.ns_buckets(mesh=mesh)     # memoised per mesh
@@ -144,7 +144,7 @@ def test_stack_unstack_roundtrip_property(m, n, stack, seed):
               "s": jax.random.normal(k, (stack, m, n))}
     metas = {n_: ParamMeta("spectral", 1.0, 1 if n_ == "s" else 0)
              for n_ in params}
-    plan = LayerPlan.build(params, metas)
+    plan = LayerPlan.build(params, metas, use_pallas=False)
     buckets = plan.ns_buckets()
     assert sum(b.batch for b in buckets) == stack + 2
     flat = plan.flatten(params)
@@ -232,7 +232,7 @@ def test_step_dispatch_count_regression(key):
         jaxpr = jax.make_jaxpr(
             lambda s, b: step(s, _quadratic_grad, b, 0.05))(state, batch)
         counts[bucketing] = count_ns_dispatches(jaxpr.jaxpr)
-    plan = LayerPlan.build(params, metas)
+    plan = LayerPlan.build(params, metas, use_pallas=False)
     n_buckets = len(plan.ns_buckets())
     n_spectral = sum(1 for lp in plan.leaves if lp.meta.lmo == "spectral")
     ns_steps = 5

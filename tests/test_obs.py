@@ -211,7 +211,7 @@ def test_metrics_on_bit_equal_and_content(key):
     assert vals["ef/rel_err/bias"] == 0.0
     # static wire accounting matches the layouts exactly
     plan = LayerPlan.build(params, metas, w2s="top10+natural",
-                           s2w="natural")
+                           s2w="natural", use_pallas=False)
     dt = _opt().cfg.wire_dtype
     assert vals["wire/bytes_w2s"] == plan.wire_layout(dt).total_nbytes
     assert vals["wire/bytes_s2w"] == \
@@ -267,7 +267,8 @@ def test_span_metadata_gated_by_trace_spans(key):
 
     on = _lowered_debug_text(params, metas, key, trace_spans=True)
     n_stages = LayerPlan.build(params, metas, w2s="top10+natural",
-                               s2w="natural").stage_plan().n_stages
+                               s2w="natural",
+                               use_pallas=False).stage_plan().n_stages
     for name in PHASE_SPANS:
         assert name in on, name
     for k in range(n_stages):
@@ -395,7 +396,8 @@ def test_profiler_capture_contains_all_spans(key, tmp_path):
     assert blob, "profiler produced no trace files"
 
     n_stages = LayerPlan.build(params, metas, w2s="top10+natural",
-                               s2w="natural").stage_plan().n_stages
+                               s2w="natural",
+                               use_pallas=False).stage_plan().n_stages
     assert n_stages > 1
     expected = list(PHASE_SPANS)
     for k in range(n_stages):

@@ -45,7 +45,8 @@ def _tree(key):
 
 def test_stage_plan_partitions_leaves(key):
     params, metas = _tree(key)
-    plan = LayerPlan.build(params, metas, w2s="top10")
+    plan = LayerPlan.build(params, metas, w2s="top10",
+                           use_pallas=False)
     sp = plan.stage_plan()
     assert sp is plan.stage_plan()                       # memoised
     # every leaf in exactly one stage
@@ -70,7 +71,8 @@ def test_stage_plan_partitions_leaves(key):
 
 def test_stage_plan_cap_merges_smallest_tail(key):
     params, metas = _tree(key)
-    plan = LayerPlan.build(params, metas, w2s="top10")
+    plan = LayerPlan.build(params, metas, w2s="top10",
+                           use_pallas=False)
     auto = plan.stage_plan()
     assert auto.n_stages == 4          # eager + 3 buckets
     capped = plan.stage_plan(wire_stages=3)
@@ -95,7 +97,8 @@ def test_s2w_issue_order_descending_receive_work(key):
     counts — the decompress+apply chain each broadcast must hide), NOT by
     the NS FLOPs that ordered the w2s stages."""
     params, metas = _tree(key)
-    plan = LayerPlan.build(params, metas, w2s="top10", s2w="natural")
+    plan = LayerPlan.build(params, metas, w2s="top10", s2w="natural",
+                           use_pallas=False)
     sp = plan.stage_plan()
     order = s2w_issue_order(plan, sp)
     assert sorted(order) == list(range(sp.n_stages))
@@ -119,7 +122,8 @@ def test_s2w_issue_order_descending_receive_work(key):
 def test_stage_plan_no_buckets_is_single_stage(key):
     params = {"v": jax.random.normal(key, (8,))}
     metas = {"v": ParamMeta("sign", 1.0, 0)}
-    plan = LayerPlan.build(params, metas, w2s="top10")
+    plan = LayerPlan.build(params, metas, w2s="top10",
+                           use_pallas=False)
     sp = plan.stage_plan()
     assert sp.n_stages == 1 and sp.stages[0].leaf_ids == (0,)
 
@@ -128,7 +132,8 @@ def test_stage_plan_no_buckets_is_single_stage(key):
 
 def test_staged_layout_byte_exact_repartition(key):
     params, metas = _tree(key)
-    plan = LayerPlan.build(params, metas, w2s="top10+natural")
+    plan = LayerPlan.build(params, metas, w2s="top10+natural",
+                           use_pallas=False)
     sp = plan.stage_plan()
     layout = plan.wire_layout(jnp.bfloat16)
     staged = plan.staged_wire_layout(jnp.bfloat16, sp)
@@ -181,7 +186,8 @@ def _payloads_for(plan, key, n_workers=2, direction="w2s"):
 
 def test_staged_pack_unpack_roundtrip_bitexact(key):
     params, metas = _tree(key)
-    plan = LayerPlan.build(params, metas, w2s="top10+natural")
+    plan = LayerPlan.build(params, metas, w2s="top10+natural",
+                           use_pallas=False)
     staged = plan.staged_wire_layout(jnp.bfloat16, plan.stage_plan())
     payloads = _payloads_for(plan, key)
     for k, ids in enumerate(staged.stage_leaf_ids):
@@ -216,7 +222,8 @@ def test_staged_roundtrip_property(name, direction, L, m, n, stages, seed):
     metas = {"w": ParamMeta("spectral", 1.0, 0),
              "s": ParamMeta("spectral", 1.0, 1),
              "v": ParamMeta("sign", 1.0, 0, compressible=False)}
-    plan = LayerPlan.build(params, metas, w2s=name, s2w=name)
+    plan = LayerPlan.build(params, metas, w2s=name, s2w=name,
+                           use_pallas=False)
     staged = plan.staged_wire_layout(
         jnp.bfloat16, plan.stage_plan(wire_stages=stages),
         direction=direction)

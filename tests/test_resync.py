@@ -199,7 +199,7 @@ def test_rejoin_within_ring_is_bit_identical_lossless():
     # subclass, so s2w != "identity" (the resync guard is a string
     # check) while the leg itself is exact
     compressors_mod.REGISTRY.setdefault(
-        "identity-wire", lambda: type("IdentityWire", (Identity,), {})())
+        "identity-wire", lambda up: type("IdentityWire", (Identity,), {})())
     state, auxes = _run(
         _resync_cfg(s2w="identity-wire", resync=4,
                     masks=_absence_masks()), n_steps=12)

@@ -20,7 +20,8 @@ def _single_leaf_layout(name, shape, stack_dims=0, lmo="spectral",
                         direction="w2s"):
     params = {"p": jax.ShapeDtypeStruct(shape, jnp.float32)}
     metas = {"p": ParamMeta(lmo, 1.0, stack_dims)}
-    plan = LayerPlan.build(params, metas, **{direction: name})
+    plan = LayerPlan.build(params, metas, **{direction: name},
+                           use_pallas=False)
     return plan, plan.wire_layout(jnp.bfloat16, direction=direction)
 
 
@@ -131,7 +132,8 @@ def test_layout_offset_table_is_static_and_contiguous():
     metas = {"w": ParamMeta("spectral", 1.0, 1),
              "v": ParamMeta("sign", 1.0, 0, compressible=False),
              "e": ParamMeta("sign", 1.0, 0)}
-    plan = LayerPlan.build(params, metas, w2s="top10+natural")
+    plan = LayerPlan.build(params, metas, w2s="top10+natural",
+                           use_pallas=False)
     layout = plan.wire_layout(jnp.bfloat16)
     assert plan.wire_layout(jnp.bfloat16) is layout       # memoised
     pos = 0
@@ -167,9 +169,10 @@ def test_narrow_width_selection_per_domain():
 
 def test_index_domains_column_topk():
     assert index_domains(C.ColumnTopK(0.1), (128, 300)) == {"indices": 300}
-    assert index_domains(C.WithNatural(C.TopK(0.1)), (16, 8)) == \
+    assert index_domains(
+        C.WithNatural(C.TopK(0.1), use_pallas=False), (16, 8)) == \
         {"indices": 128}
-    assert index_domains(C.Natural(), (16, 8)) == {}
+    assert index_domains(C.Natural(use_pallas=False), (16, 8)) == {}
 
 
 def test_packed_step_equals_unpacked_step_bitexact(key):
@@ -232,7 +235,7 @@ def test_identity_subclass_stays_lossless(key):
 def test_with_natural_identity_end_to_end(key):
     """WithNatural(Identity): compress/decompress/payload_bytes agree
     (satellite: the payload_bytes Identity branch is now reachable)."""
-    comp = C.get_compressor("identity+natural")
+    comp = C.get_compressor("identity+natural", use_pallas=False)
     assert isinstance(comp.inner, C.Identity)
     assert not comp.lossless_wire                  # the wrapper quantises
     shape = (13, 21)
@@ -331,3 +334,40 @@ def test_checkpoint_roundtrip_with_s2w_wire_engaged(tmp_path, key):
         state, _ = step(state, jnp.zeros((2, 1)), 0.01)
         state2, _ = step(state2, jnp.zeros((2, 1)), 0.01)
     _tree_equal(state, state2)
+
+
+@pytest.mark.parametrize("use_pallas", [True, False])
+@pytest.mark.parametrize("name", ["top10+natural", "rank10+natural",
+                                  "natural"])
+def test_plan_hands_one_kernel_choice_to_compressors_and_codecs(
+        name, use_pallas):
+    """LayerPlan.build resolves ``use_pallas`` once and every compressor
+    of the plan (WithNatural's inner one and the non-2D fallback
+    included) and every narrow wire codec carries that choice."""
+    params = {"w": jax.ShapeDtypeStruct((64, 96), jnp.float32),
+              "v": jax.ShapeDtypeStruct((96,), jnp.float32)}
+    metas = {"w": ParamMeta("spectral", 1.0, 0),
+             "v": ParamMeta("sign", 1.0, 0)}
+    plan = LayerPlan.build(params, metas, w2s=name, s2w=name,
+                           use_pallas=use_pallas)
+    assert plan.use_pallas is use_pallas
+    for lp in plan.leaves:
+        for comp in (lp.w2s, lp.s2w):
+            for c in (comp, getattr(comp, "inner", None)):
+                if hasattr(c, "use_pallas"):
+                    assert c.use_pallas is use_pallas, c
+    narrow = [c for d in ("w2s", "s2w")
+              for spec in plan.wire_layout(jnp.bfloat16, d).specs
+              for c in spec.codecs if isinstance(c, NarrowIntCodec)]
+    assert bool(narrow) == (name != "natural")   # TopK indices
+    assert all(c.use_pallas is use_pallas for c in narrow)
+
+
+def test_auto_kernel_choice_resolves_to_references_on_cpu():
+    """"auto" resolves once, from the platform: the jnp references here."""
+    opt = EF21Muon(EF21MuonConfig(use_pallas="auto", w2s="top10+natural"))
+    assert opt.cfg.use_pallas is (jax.default_backend() == "tpu")
+    params = {"w": jax.ShapeDtypeStruct((64, 96), jnp.float32)}
+    plan = opt.plan(params, {"w": ParamMeta("spectral", 1.0, 0)})
+    assert plan.use_pallas is opt.cfg.use_pallas
+    assert plan.leaves[0].w2s.use_pallas is opt.cfg.use_pallas
