@@ -37,6 +37,7 @@ import jax.numpy as jnp
 
 from repro.kernels import (natural_compress, natural_decompress,
                            newton_schulz)
+from repro.obs.trace import sub_span
 
 Payload = Any
 State = Any
@@ -148,7 +149,8 @@ class TopK:
         return {}
 
     def compress(self, state, x):
-        vals, idx = _flat_topk(x, self.k_for(x.shape))
+        with sub_span("ef.select"):
+            vals, idx = _flat_topk(x, self.k_for(x.shape))
         return {"values": vals, "indices": idx}, state
 
     def decompress(self, payload, shape, dtype):

@@ -19,6 +19,8 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from repro.obs.trace import sub_span
+
 
 def ef_compress_step(comp, comp_state: Any, estimate: jax.Array,
                      target: jax.Array,
@@ -36,8 +38,10 @@ def ef_compress_step(comp, comp_state: Any, estimate: jax.Array,
     if getattr(comp, "lossless_wire", False):
         wire_dtype = jnp.float32
     payload, comp_state = comp.compress(comp_state, diff.astype(wire_dtype))
-    delta = comp.decompress(payload, diff.shape, jnp.float32)
-    new_estimate = (estimate.astype(jnp.float32) + delta).astype(estimate.dtype)
+    with sub_span("ef.update"):
+        delta = comp.decompress(payload, diff.shape, jnp.float32)
+        new_estimate = (estimate.astype(jnp.float32)
+                        + delta).astype(estimate.dtype)
     return payload, comp_state, new_estimate
 
 

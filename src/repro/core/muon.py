@@ -49,7 +49,8 @@ from repro.dist.sharding import worker_axis_for
 from repro.kernels import resolve_use_pallas
 from repro.obs.metrics import (MetricSet, leaf_names, orth_residual,
                                rel_error, worker_mean_norm)
-from repro.obs.trace import PHASE_SPANS, phase_span, wire_stage_span
+from repro.obs.trace import (PHASE_SPANS, phase_span, sub_span,
+                             wire_stage_span)
 
 from .error_feedback import apply_payload, ef_compress_step
 from .lmo import default_radius_scale, lmo_direction, lmo_direction_batched
@@ -107,12 +108,11 @@ class EF21MuonConfig:
                                    # the step lowers identically (the
                                    # metric reads never feed the update,
                                    # so the on arm is value-bit-equal)
-    trace_spans: bool = False      # jax.named_scope the five phases and
-                                   # every staged wire collective (§10)
-                                   # so xprof shows the §8 overlap by
-                                   # name; off => no op-metadata change
-                                   # (host TraceAnnotations are always
-                                   # on — they never touch the lowering)
+    trace_spans: bool = False      # jax.named_scope the five phases,
+                                   # their sub-phases and every staged
+                                   # wire collective (§10) so xprof shows
+                                   # the §8 overlap by name; off => no
+                                   # op-metadata change
     participation: Any = "full"    # elastic worker participation (§11):
                                    # "full" (every worker — takes the
                                    # exact pre-§11 code path, lowering-
@@ -445,10 +445,10 @@ class EF21Muon:
 
             # Observability (§10): in-graph MetricSet + phase/wire spans.
             # Both default off; the off arm takes the identical code path
-            # (phase_span without graph= is a host TraceAnnotation only,
-            # never a lowering change) so it compiles byte-identical to a
-            # build without the obs layer. Metric reads never feed the
-            # update, so the metrics-on arm stays value-bit-equal.
+            # (phase_span without graph= and every sub_span are no-ops)
+            # so it compiles byte-identical to a build without the obs
+            # layer. Metric reads never feed the update, so the
+            # metrics-on arm stays value-bit-equal.
             gspan = cfg.trace_spans
             mset = MetricSet() if cfg.metrics else None
             lnames = leaf_names(state["x"]) if cfg.metrics else None
@@ -736,9 +736,10 @@ class EF21Muon:
 
             def recv_leaf(i, pl, gs, part=None):
                 lp = plan.leaves[i]
-                d = per_device(vmap_n(lambda s: lp.w2s.decompress(
-                    s, lp.slice_shape, jnp.float32),
-                    lp.meta.stack_dims + 1))(pl)
+                with sub_span("ef.decompress"):
+                    d = per_device(vmap_n(lambda s: lp.w2s.decompress(
+                        s, lp.slice_shape, jnp.float32),
+                        lp.meta.stack_dims + 1))(pl)
                 if rep is not None:
                     d = jax.lax.with_sharding_constraint(d, rep)
                 if part is not None:

@@ -24,7 +24,7 @@ from repro.data import SyntheticLM
 from repro.launch.compile_cache import enable_compile_cache
 from repro.models.api import build_model
 from repro.obs.sink import MetricsWriter, run_manifest
-from repro.obs.trace import span_summary
+from repro.obs.trace import span, span_summary
 from repro.train.checkpoint import load_checkpoint, save_checkpoint
 from repro.train.faults import parse_faults
 from repro.train.supervisor import Supervisor, SupervisorConfig
@@ -198,21 +198,26 @@ def main():
                 # simulated power loss (crash:step=s): fresh runs only,
                 # so the --resume run sails past the crash step
                 faults.host_crash(i, start_step=start)
-            if sup is not None:
-                result, rs_state, rs_step = sup.run_step(
-                    step_fn, state, data.batch_at(i), sched(i),
-                    step=i, faults=faults)
-                if result is None:
-                    # checkpoint-reload recovery: rewind the loop to the
-                    # last-good generation and re-step from there
-                    state, i = rs_state, rs_step
-                    print(f"recovered from {args.checkpoint} "
-                          f"@ step {i}", flush=True)
-                    continue
-                state, aux = result
-                sup.maybe_checkpoint(state, i)
-            else:
-                state, aux = step_fn(state, data.batch_at(i), sched(i))
+            with span("data/batch"):
+                batch = data.batch_at(i)
+            # step i's dispatch (supervised: its retries and checkpoint)
+            # as one step of an operator's profiler capture
+            with jax.profiler.StepTraceAnnotation("train_step", step_num=i):
+                if sup is not None:
+                    result, rs_state, rs_step = sup.run_step(
+                        step_fn, state, batch, sched(i), step=i,
+                        faults=faults)
+                    if result is None:
+                        # checkpoint-reload recovery: rewind the loop to
+                        # the last-good generation and re-step from there
+                        state, i = rs_state, rs_step
+                        print(f"recovered from {args.checkpoint} "
+                              f"@ step {i}", flush=True)
+                        continue
+                    state, aux = result
+                    sup.maybe_checkpoint(state, i)
+                else:
+                    state, aux = step_fn(state, batch, sched(i))
             if "resync_replayed" in aux:
                 resync_replayed += int(aux["resync_replayed"])
                 resync_full += int(aux["resync_full"])

@@ -26,6 +26,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
 from repro.core.muon import ParamMeta
+from repro.obs.trace import sub_span
 
 from .common import (apply_rope, attention, chunked_softmax_xent,
                      decode_attention, embed_init, layer_norm, logits_last,
@@ -351,18 +352,22 @@ def _moe_ffn(cfg: ArchConfig, p: dict, x: jax.Array) -> tuple[jax.Array, jax.Arr
 def _block(cfg: ArchConfig, p: dict, x: jax.Array, pos: jax.Array,
            cache: dict | None, t, mode: str, is_moe: bool):
     attn_fn = _mla_attn if cfg.mla is not None else _gqa_attn
-    h = _norm(cfg, p, "ln1", x)
-    a_out, new_cache = attn_fn(cfg, p["attn"], h, pos, cache, t, mode)
-    if cfg.mla is None:
-        b_, s = x.shape[:2]
-        a_out = a_out.reshape(b_, s, cfg.n_heads * cfg.hd) @ p["attn"]["wo"]
-    x = x + a_out
-    h = _norm(cfg, p, "ln2", x)
-    if is_moe:
-        f_out, aux = _moe_ffn(cfg, p["moe"], h)
-    else:
-        f_out, aux = _mlp(cfg, p["mlp"], h), jnp.zeros((), jnp.float32)
-    return x + f_out, new_cache, aux
+    with sub_span("lm.attn"):
+        h = _norm(cfg, p, "ln1", x)
+        a_out, new_cache = attn_fn(cfg, p["attn"], h, pos, cache, t, mode)
+        if cfg.mla is None:
+            b_, s = x.shape[:2]
+            a_out = (a_out.reshape(b_, s, cfg.n_heads * cfg.hd)
+                     @ p["attn"]["wo"])
+        x = x + a_out
+    with sub_span("lm.mlp"):
+        h = _norm(cfg, p, "ln2", x)
+        if is_moe:
+            f_out, aux = _moe_ffn(cfg, p["moe"], h)
+        else:
+            f_out, aux = _mlp(cfg, p["mlp"], h), jnp.zeros((), jnp.float32)
+        x = x + f_out
+    return x, new_cache, aux
 
 
 # ---------------------------------------------------------------- the model
@@ -443,19 +448,20 @@ class Transformer:
 
     def _embed_in(self, params: dict, batch: dict):
         cfg = self.cfg
-        if "embeds" in batch:
-            x = batch["embeds"].astype(jnp.dtype(cfg.dtype))
-            pos = batch["pos"]
-        else:
-            x = params["embed"][batch["tokens"] if "tokens" in batch
-                                else batch["token"]]
-            s = x.shape[1]
-            pos = jnp.broadcast_to(jnp.arange(s)[None], x.shape[:2])
-            if cfg.rope == "mrope":
-                pos = jnp.broadcast_to(pos[..., None], pos.shape + (3,))
-        if cfg.rope == "learned":
-            x = x + params["pos_embed"][
-                jnp.clip(pos, 0, cfg.max_position - 1)]
+        with sub_span("lm.vocab"):
+            if "embeds" in batch:
+                x = batch["embeds"].astype(jnp.dtype(cfg.dtype))
+                pos = batch["pos"]
+            else:
+                x = params["embed"][batch["tokens"] if "tokens" in batch
+                                    else batch["token"]]
+                s = x.shape[1]
+                pos = jnp.broadcast_to(jnp.arange(s)[None], x.shape[:2])
+                if cfg.rope == "mrope":
+                    pos = jnp.broadcast_to(pos[..., None], pos.shape + (3,))
+            if cfg.rope == "learned":
+                x = x + params["pos_embed"][
+                    jnp.clip(pos, 0, cfg.max_position - 1)]
         return x, pos
 
     def _unembed(self, params: dict):
@@ -468,8 +474,9 @@ class Transformer:
         cfg = self.cfg
         x, pos = self._embed_in(params, batch)
         h, _, aux = self._run(params, x, pos, None, None, "full", remat)
-        un = self._unembed(params)
-        out = chunked_softmax_xent(h, un, batch["labels"])
+        with sub_span("lm.vocab"):
+            out = chunked_softmax_xent(h, self._unembed(params),
+                                       batch["labels"])
         if cfg.moe:
             out = out + 0.01 * aux / max(self.n_moe, 1)
         if cfg.mtp and "tokens" in batch:

@@ -1,32 +1,42 @@
 """Trace spans — names for the step's phase structure (DESIGN.md §10).
 
-Two span kinds, one naming convention:
+Three span kinds, one naming convention:
 
 * ``phase_span(name, graph=...)`` wraps a block of *traced* optimizer
-  code. It always enters a ``jax.profiler.TraceAnnotation`` (a host-side
-  TraceMe: real timing when the step runs eagerly, trace-time-only noise
-  under jit — never a lowering change), and, when ``graph`` is true,
-  additionally a ``jax.named_scope`` so the ops lowered inside carry the
-  span name as op metadata and an xprof capture of the jitted step shows
-  the §8 overlap structure by name. ``graph`` is gated by
+  code. When ``graph`` is true it enters a ``jax.named_scope``, so the
+  ops lowered inside carry the span name as op metadata and a profiler
+  capture of the jitted step shows the §8 overlap structure by name;
+  otherwise it does nothing. ``graph`` is gated by
   ``EF21MuonConfig.trace_spans`` because op metadata appears in the
   compiled HLO text — the spans-off arm must lower byte-identical to a
   build without this module.
 
-* ``span(name)`` times a *host-side* (non-jit) phase — plan build,
-  layout memoisation, checkpoint I/O — into the process-wide
-  ``SpanRecorder`` (and the same TraceAnnotation, so host phases show up
-  in profiler captures too). ``span_summary()`` renders the recorder as
-  rows for the metrics sink / the train CLI's end-of-run table.
+* ``sub_span(name)`` names one level below a phase: the model's
+  attention, MLP and vocabulary work inside the gradients phase, the
+  TopK selection and EF21 estimate update inside the compress, the
+  server's decompress inside the receive. It enters its
+  ``jax.named_scope`` only while a ``phase_span(..., graph=True)`` is
+  open (a context variable that ``phase_span`` sets), so the models and
+  compressors take no flag and the spans-off arm still lowers
+  byte-identical.
 
-Span names are the contract the slow profiler test asserts against:
-``PHASE_SPANS`` for the five algorithm phases of ``core/muon.py``, and
-``wire_stage_span(direction, k)`` for stage ``k``'s gather/broadcast in
-``dist/pipeline.py``'s issue order.
+* ``span(name)`` times a *host-side* (non-jit) phase — plan build,
+  layout memoisation, checkpoint I/O, batch making — into the
+  process-wide ``SpanRecorder`` (and a ``jax.profiler.TraceAnnotation``,
+  so host phases show up in profiler captures too). ``span_summary()``
+  renders the recorder as rows for the metrics sink / the train CLI's
+  end-of-run table.
+
+Span names are the contract the metadata tests assert against:
+``PHASE_SPANS`` for the five algorithm phases of ``core/muon.py``,
+``SUB_SPANS`` for the sub-phases, and ``wire_stage_span(direction, k)``
+for stage ``k``'s gather/broadcast in ``dist/pipeline.py``'s issue
+order.
 """
 from __future__ import annotations
 
 import contextlib
+import contextvars
 import threading
 import time
 from collections import OrderedDict
@@ -43,6 +53,21 @@ PHASE_SPANS = (
     "ef21/p5_lmo",           # layer-wise LMO (bucketed Newton-Schulz)
 )
 
+# One level of sub-phases inside phases 2-4 (``sub_span``). Dotted
+# names, so that no JAX primitive's name (``select_n``, ...) can equal
+# one as a path segment of an op name.
+SUB_SPANS = (
+    "lm.attn",        # p2: ln1 + attention + wo + residual, per block
+    "lm.mlp",         # p2: ln2 + MLP/MoE FFN + residual, per block
+    "lm.vocab",       # p2: token/position lookup, tied head + xent
+    "ef.select",      # p3: TopK's abs, top_k and value gather
+    "ef.update",      # p3: the sender's own decompress + estimate add
+    "ef.decompress",  # p4: the server's payload -> dense delta
+)
+
+# True while a graph phase_span is open: sub_span scopes only then.
+_GRAPH_PHASE = contextvars.ContextVar("repro_graph_phase", default=False)
+
 
 def wire_stage_span(direction: str, k: int) -> str:
     """Span name of stage ``k``'s u8 collective: ``direction`` is
@@ -54,13 +79,28 @@ def wire_stage_span(direction: str, k: int) -> str:
 
 @contextlib.contextmanager
 def phase_span(name: str, graph: bool = False):
-    """Span around traced optimizer code. Host TraceAnnotation always
-    (lowering-neutral); ``jax.named_scope`` only when ``graph`` — the
-    op-metadata arm the HLO-identity guard keeps off by default."""
-    with contextlib.ExitStack() as stack:
-        stack.enter_context(jax.profiler.TraceAnnotation(name))
-        if graph:
-            stack.enter_context(jax.named_scope(name))
+    """Span around traced optimizer code: ``jax.named_scope(name)`` when
+    ``graph`` (the op-metadata arm the HLO-identity guard keeps off by
+    default), nothing otherwise."""
+    if not graph:
+        yield
+        return
+    token = _GRAPH_PHASE.set(True)
+    try:
+        with jax.named_scope(name):
+            yield
+    finally:
+        _GRAPH_PHASE.reset(token)
+
+
+@contextlib.contextmanager
+def sub_span(name: str):
+    """``jax.named_scope(name)`` inside an open graph ``phase_span``,
+    nothing outside one."""
+    if not _GRAPH_PHASE.get():
+        yield
+        return
+    with jax.named_scope(name):
         yield
 
 
