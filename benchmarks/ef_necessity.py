@@ -19,7 +19,7 @@ def run(fast: bool = False):
     def full_grad(x):
         return jnp.mean(jnp.stack([grad_j(x, j) for j in range(3)]), 0)
 
-    comp = TopK(0.34)
+    comp = TopK(0.34, use_pallas=False)
     lr, steps = 0.1, 100 if fast else 400
     x0 = jnp.array([1.0, 0.7, -0.3])
 

@@ -19,7 +19,7 @@ x = jax.random.normal(key, (64, 48))
 
 print(f"{'compressor':22s} {'norm':10s} {'alpha_emp':>9s} {'bytes':>8s}")
 for comp, kind in [
-        (TopK(0.1), "frobenius"),
+        (TopK(0.1, use_pallas=False), "frobenius"),
         (TopKSVD(rank=4), "spectral"),
         (TopKSVD(rank=4), "nuclear"),
         (TopKSVD(rank=4), "frobenius"),
@@ -28,7 +28,7 @@ for comp, kind in [
         (Natural(use_pallas=False), "linf"),
         (RandomDropout(0.6), "frobenius"),
         (RankK(fraction=0.15, use_pallas=False), "frobenius"),
-        (WithNatural(TopK(0.15), use_pallas=False), "frobenius")]:
+        (WithNatural(TopK(0.15, use_pallas=False), use_pallas=False), "frobenius")]:
     a = empirical_alpha(comp, key, x, n_trials=4, norm_kind=kind)
     b = comp.payload_bytes(x.shape, jnp.bfloat16)
     print(f"{comp.name:22s} {kind:10s} {a:9.3f} {b:8d}")

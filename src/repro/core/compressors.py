@@ -2,7 +2,7 @@
 
 Functional API (jit-safe, fixed payload shapes):
 
-    comp = TopK(fraction=0.1)
+    comp = TopK(fraction=0.1, use_pallas=False)
     state = comp.init(key, shape, dtype)          # sketches / PRNG, may be {}
     payload, state = comp.compress(state, x)      # payload: pytree of small arrays
     x_hat = comp.decompress(payload, shape, dtype)
@@ -36,7 +36,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import (natural_compress, natural_decompress,
-                           newton_schulz)
+                           newton_schulz, topk_select)
 from repro.obs.trace import sub_span
 
 Payload = Any
@@ -127,16 +127,16 @@ class RandomDropout:
         return int(self.p * _nelem(shape) * _itemsize(dtype)) + 1
 
 
-def _flat_topk(x: jax.Array, k: int):
-    flat = x.reshape(-1)
-    vals, idx = jax.lax.top_k(jnp.abs(flat), k)
-    return flat[idx], idx.astype(jnp.int32)
-
-
 @dataclass(frozen=True)
 class TopK:
-    """Keep the k = ceil(fraction * n) largest-magnitude entries."""
+    """Keep the k = ceil(fraction * n) largest-magnitude entries.
+
+    The payload holds them in ascending index order: ``lax.top_k``'s set
+    (ties to the lower index), selected without a sort by an exact
+    magnitude threshold (``kernels.ops.topk_select``)."""
     fraction: float = 0.1
+    # selection kernel or reference, resolved by the caller
+    use_pallas: bool = field(kw_only=True)
 
     @property
     def name(self):
@@ -150,7 +150,8 @@ class TopK:
 
     def compress(self, state, x):
         with sub_span("ef.select"):
-            vals, idx = _flat_topk(x, self.k_for(x.shape))
+            vals, idx = topk_select(x.reshape(-1), self.k_for(x.shape),
+                                    use_pallas=self.use_pallas)
         return {"values": vals, "indices": idx}, state
 
     def decompress(self, payload, shape, dtype):
@@ -408,17 +409,19 @@ def empirical_alpha(comp, key, x, n_trials: int = 8, norm_kind: str = "frobenius
 
 
 # name -> factory(use_pallas): the kernel choice is handed to every
-# compressor that has kernels (Natural, RankK and their WithNatural)
+# compressor that has kernels (Natural, TopK, RankK and their WithNatural)
 REGISTRY = {
     "identity": lambda up: Identity(),
     "natural": lambda up: Natural(use_pallas=up),
     "identity+natural": lambda up: WithNatural(Identity(), use_pallas=up),
-    "top5": lambda up: TopK(0.05),
-    "top10": lambda up: TopK(0.10),
-    "top15": lambda up: TopK(0.15),
-    "top20": lambda up: TopK(0.20),
-    "top10+natural": lambda up: WithNatural(TopK(0.10), use_pallas=up),
-    "top15+natural": lambda up: WithNatural(TopK(0.15), use_pallas=up),
+    "top5": lambda up: TopK(0.05, use_pallas=up),
+    "top10": lambda up: TopK(0.10, use_pallas=up),
+    "top15": lambda up: TopK(0.15, use_pallas=up),
+    "top20": lambda up: TopK(0.20, use_pallas=up),
+    "top10+natural": lambda up: WithNatural(
+        TopK(0.10, use_pallas=up), use_pallas=up),
+    "top15+natural": lambda up: WithNatural(
+        TopK(0.15, use_pallas=up), use_pallas=up),
     "rank5": lambda up: RankK(fraction=0.05, use_pallas=up),
     "rank10": lambda up: RankK(fraction=0.10, use_pallas=up),
     "rank15": lambda up: RankK(fraction=0.15, use_pallas=up),

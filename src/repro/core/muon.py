@@ -48,10 +48,11 @@ from repro.dist.resync import (init_resync_state, replay_masks,
 from repro.dist.sharding import worker_axis_for
 from repro.kernels import resolve_use_pallas
 from repro.obs.metrics import (MetricSet, leaf_names, orth_residual,
-                               rel_error, worker_mean_norm)
+                               rel_error, topk_ties, worker_mean_norm)
 from repro.obs.trace import (PHASE_SPANS, phase_span, sub_span,
                              wire_stage_span)
 
+from .compressors import TopK
 from .error_feedback import apply_payload, ef_compress_step
 from .lmo import default_radius_scale, lmo_direction, lmo_direction_batched
 
@@ -692,6 +693,7 @@ class EF21Muon:
                         *fl, extra_vmap=1), 3), wspec)(
                             plan.flatten(state["cw_state"]), gw_old,
                             plan.flatten(m_new))
+                sent = payloads      # as compressed, before the wire
 
             # ---- 4.+5. server receive + layer-wise LMO. Shared per-leaf
             # pieces first: decompress one leaf's gathered payloads, pin
@@ -932,6 +934,10 @@ class EF21Muon:
                     mset.add(f"ef/rel_err/{nm}", rel_error(err, v))
                     mset.add(f"ef/momentum_norm/{nm}",
                              worker_mean_norm(m_flat[i]))
+                    w2s = plan.leaves[i].w2s
+                    if isinstance(getattr(w2s, "inner", w2s), TopK):
+                        mset.add(f"ef/topk_ties/{nm}", topk_ties(
+                            v.astype(cfg.wire_dtype), sent[i]["indices"]))
                     if wnew_f is not None:
                         # EF21-P model-estimate error ‖X - W‖ (s2w leg)
                         mset.add(f"efp/err_norm/{nm}", worker_mean_norm(

@@ -52,7 +52,7 @@ def resolve_compressor(name: str, slice_shape: tuple[int, ...],
     inner = comp.inner if isinstance(comp, comp_lib.WithNatural) else comp
     if isinstance(inner, (comp_lib.RankK, comp_lib.TopKSVD)) \
             and len(slice_shape) != 2:
-        fallback = comp_lib.TopK(0.25)
+        fallback = comp_lib.TopK(0.25, use_pallas=use_pallas)
         if isinstance(comp, comp_lib.WithNatural):
             return comp_lib.WithNatural(fallback, use_pallas=use_pallas)
         return fallback

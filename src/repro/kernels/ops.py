@@ -15,6 +15,7 @@ from .bitpack import pack_bits, unpack_bits
 from .natural_pack import natural_encode
 from .newton_schulz import (fused_ns_feasible, ns_iteration_fused,
                             ns_iteration_pallas)
+from .topk_select import topk_select as topk_select_kernel
 
 NS_COEFFS = ref.NS_COEFFS
 
@@ -225,3 +226,35 @@ def natural_decompress(code: jax.Array, packed_sign: jax.Array,
                        interpret=interpret)[:n]
     vals = ref.natural_decompress_ref(code, sign)
     return vals.reshape(shape).astype(dtype)
+
+
+def topk_select(flat: jax.Array, k: int, *, use_pallas: bool,
+                interpret: bool = False) -> tuple[jax.Array, jax.Array]:
+    """The k largest-magnitude entries of a 1-D ``flat`` as (values in
+    its dtype, int32 indices), in ascending index order: ``lax.top_k``'s
+    set, ties to the lower index, found without a sort
+    (``ref.topk_threshold``). The jnp path compacts with ``jnp.nonzero``;
+    the Pallas path with the ``topk_select`` kernel, one call for every
+    slice of the enclosing ``vmap``s. Both return the same payload bit
+    for bit."""
+    if not use_pallas:
+        return ref.topk_select_ref(flat, k)
+
+    @jax.custom_batching.custom_vmap
+    def select(x):                            # x [..., n]
+        threshold = lambda s: ref.topk_threshold(s, k)
+        for _ in x.shape[:-1]:
+            threshold = jax.vmap(threshold)
+        t, c = threshold(x)
+        idx, val = topk_select_kernel(x, t, c, k, interpret=interpret)
+        return val.astype(x.dtype), idx
+
+    @select.def_vmap
+    def _batched(axis_size, in_batched, x):
+        # each enclosing vmap adds a leading dim; the innermost call of
+        # ``select`` folds them all into the kernel's slice axis
+        if not in_batched[0]:
+            x = jnp.broadcast_to(x, (axis_size,) + x.shape)
+        return select(x), (True, True)
+
+    return select(flat)

@@ -120,3 +120,75 @@ def natural_decompress_ref(code: jax.Array, sign: jax.Array) -> jax.Array:
     bits = (sign.astype(jnp.uint16) << 15) | (code.astype(jnp.uint16) << 7)
     bits = jnp.where(code == 0, sign.astype(jnp.uint16) << 15, bits)
     return jax.lax.bitcast_convert_type(bits.astype(jnp.uint16), jnp.bfloat16)
+
+
+def magnitude_key32(x: jax.Array) -> jax.Array:
+    """int32 key of |x| for a 16- or 32-bit float ``x``: non-negative
+    IEEE floats order as their bit patterns, so the keys order as the
+    magnitudes, -0 and +0 alike. A 16-bit pattern fills the high half,
+    which is the key of its value widened to f32 for bf16.
+
+    The key is read from the bits of ``x`` itself: XLA may keep a value
+    it rounded to bf16 at f32 where only float arithmetic follows (and
+    on the TPU it did, in the compiled step), which would rank entries
+    that tie in bf16 apart, unlike the kernel that reads the bf16
+    array."""
+    if x.dtype.itemsize == 2:
+        bits = jax.lax.bitcast_convert_type(x, jnp.uint16).astype(jnp.int32)
+        return (bits & 0x7FFF) << 16
+    if x.dtype.itemsize == 4:
+        return jax.lax.bitcast_convert_type(x, jnp.int32) & 0x7FFFFFFF
+    raise TypeError(f"no magnitude key for {x.dtype}")
+
+
+TIE_BLOCK = 1024   # entries per tie count of ``topk_threshold``
+
+
+def topk_threshold(flat: jax.Array, k: int) -> tuple[jax.Array, jax.Array]:
+    """The keep rule of ``lax.top_k(abs(flat), k)``'s set, without a sort:
+    (t, c) such that entry i is kept iff key_i > t, or key_i == t and
+    i < c (``magnitude_key32`` keys; ties go to the lower index, as in
+    ``lax.top_k``). Exactly k entries are kept.
+
+    t is the k-th largest key, built bit by bit from the top: one
+    compare-and-count pass per mantissa and exponent bit of the dtype
+    (15 for bf16, 31 for f32). c is one past the r-th entry equal to t,
+    r = k - #(key > t): a count of ties per ``TIE_BLOCK`` entries, a
+    cumulative sum over those counts, and a cumulative sum inside the
+    one block where the r-th tie falls."""
+    n = flat.shape[0]
+    low = 32 - 8 * flat.dtype.itemsize       # key bits below are zero
+
+    def bit_pass(j, t):
+        cand = t | jnp.left_shift(jnp.int32(1), 30 - j)
+        cnt = jnp.sum((magnitude_key32(flat) >= cand).astype(jnp.int32))
+        return jnp.where(cnt >= k, cand, t)
+
+    t = jax.lax.fori_loop(0, 31 - low, bit_pass, jnp.int32(0))
+    key = magnitude_key32(flat)
+    r = k - jnp.sum((key > t).astype(jnp.int32))
+    nb = -(-n // TIE_BLOCK)
+    tie = jnp.pad((key == t).astype(jnp.int32), (0, nb * TIE_BLOCK - n))
+    cum = jnp.cumsum(jnp.sum(tie.reshape(nb, TIE_BLOCK), axis=1))
+    j = jnp.sum((cum < r).astype(jnp.int32))
+    before = jnp.where(j > 0, cum[jnp.maximum(j - 1, 0)], 0)
+    seg = jax.lax.dynamic_slice(tie, (j * TIE_BLOCK,), (TIE_BLOCK,))
+    pos = jnp.sum((before + jnp.cumsum(seg) < r).astype(jnp.int32))
+    return t, j * TIE_BLOCK + pos + 1
+
+
+def topk_keep_mask(flat: jax.Array, t: jax.Array, c: jax.Array):
+    """The entries ``topk_threshold``'s (t, c) keep."""
+    key = magnitude_key32(flat)
+    idx = jnp.arange(flat.shape[0], dtype=jnp.int32)
+    return (key > t) | ((key == t) & (idx < c))
+
+
+def topk_select_ref(flat: jax.Array, k: int) -> tuple[jax.Array, jax.Array]:
+    """The k largest-magnitude entries of ``flat`` as (values, int32
+    indices) in ascending index order: the same set as ``lax.top_k``,
+    ties to the lower index."""
+    t, c = topk_threshold(flat, k)
+    idx = jnp.nonzero(topk_keep_mask(flat, t, c), size=k,
+                      fill_value=0)[0].astype(jnp.int32)
+    return flat[idx], idx

@@ -16,6 +16,11 @@ Metric names are ``/``-separated taxonomies (DESIGN.md §10):
   ef/rel_err/<leaf>         ‖C(v)-v‖/‖v‖   compression relative error of
                             v = M_j - G_j (0 where ‖v‖ == 0)
   ef/momentum_norm/<leaf>   ‖M_j‖          worker-mean momentum norm
+  ef/topk_ties/<leaf>       TopK leaves: kept entries whose magnitude
+                            equals the slice's smallest kept magnitude,
+                            summed over the leaf's slices, mean over
+                            workers (how much the tie cut at the lower
+                            index decides)
   efp/err_norm/<leaf>       ‖X - W‖        EF21-P server model-estimate
                             error (s2w leg only)
   ns/orth_residual/<bucket> ‖G - I‖_F      Newton-Schulz orthogonality
@@ -42,6 +47,8 @@ import re
 
 import jax
 import jax.numpy as jnp
+
+from repro.kernels.ref import magnitude_key32
 
 _NAME_RE = re.compile(r"^[A-Za-z0-9_.+\-]+(/[A-Za-z0-9_.+\-]+)*$")
 
@@ -114,6 +121,17 @@ def rel_error(num, den, lead: int = 1):
     n = jnp.sqrt(jnp.sum(jnp.square(num), axis=axes))
     d = jnp.sqrt(jnp.sum(jnp.square(den), axis=axes))
     return jnp.mean(jnp.where(d > 0, n / jnp.where(d > 0, d, 1.0), 0.0))
+
+
+def topk_ties(target, indices):
+    """Worker-mean over the leaf's slices of the kept entries tied with
+    the smallest kept magnitude. ``target`` [W, *stack, *slice] is what
+    TopK compressed, ``indices`` [W, *stack, k] its payload's."""
+    flat = target.reshape(indices.shape[:-1] + (-1,))
+    kept = magnitude_key32(jnp.take_along_axis(flat, indices, axis=-1))
+    ties = jnp.sum(kept == jnp.min(kept, axis=-1, keepdims=True), axis=-1)
+    return jnp.mean(jnp.sum(ties.reshape(ties.shape[0], -1), axis=1)
+                    .astype(jnp.float32))
 
 
 def orth_residual(d_b):

@@ -60,7 +60,7 @@ SUB_SPANS = (
     "lm.attn",        # p2: ln1 + attention + wo + residual, per block
     "lm.mlp",         # p2: ln2 + MLP/MoE FFN + residual, per block
     "lm.vocab",       # p2: token/position lookup, tied head + xent
-    "ef.select",      # p3: TopK's abs, top_k and value gather
+    "ef.select",      # p3: TopK's threshold passes + topk_select kernel
     "ef.update",      # p3: the sender's own decompress + estimate add
     "ef.decompress",  # p4: the server's payload -> dense delta
 )

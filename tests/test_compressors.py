@@ -38,13 +38,13 @@ def test_registry_roundtrip_shapes(name, key):
 def test_topk_contractive_euclidean(key):
     x = jax.random.normal(key, (32, 16))
     for frac in (0.05, 0.1, 0.2, 0.5):
-        r = _contract_ratio(C.TopK(frac), key, x)
+        r = _contract_ratio(C.TopK(frac, use_pallas=False), key, x)
         assert r <= 1.0 - frac * 0.5  # top-k beats random-k = 1 - frac
 
 
 def test_topk_exact_on_sparse(key):
     x = jnp.zeros((10, 10)).at[3, 4].set(5.0).at[7, 1].set(-2.0)
-    comp = C.TopK(0.02)  # k = 2
+    comp = C.TopK(0.02, use_pallas=False)  # k = 2
     payload, _ = comp.compress(comp.init(key, x.shape, x.dtype), x)
     xh = comp.decompress(payload, x.shape, jnp.float32)
     np.testing.assert_allclose(np.asarray(xh), np.asarray(x))
@@ -137,7 +137,7 @@ def test_with_natural_combo_bytes(key):
     """TopK+Natural / RankK+Natural payloads: float planes shrink to
     9 bits/value; indices stay int32 (paper Table 2 accounting)."""
     shape = (64, 48)
-    top = C.WithNatural(C.TopK(0.1), use_pallas=False)
+    top = C.WithNatural(C.TopK(0.1, use_pallas=False), use_pallas=False)
     k = top.inner.k_for(shape)
     assert top.payload_bytes(shape, jnp.bfloat16) == k * 4 + k + (k + 7) // 8
     rk = C.WithNatural(
@@ -162,7 +162,7 @@ def test_topk_contraction_property(frac, m, n, seed):
     """Hypothesis: Def. 1 holds for TopK with alpha = fraction for any
     shape/seed (classical result: top-k >= random-k)."""
     x = jax.random.normal(jax.random.key(seed), (m, n))
-    comp = C.TopK(frac)
+    comp = C.TopK(frac, use_pallas=False)
     payload, _ = comp.compress({}, x)
     xh = comp.decompress(payload, (m, n), jnp.float32)
     lhs = float(jnp.sum((xh - x) ** 2))
@@ -172,5 +172,5 @@ def test_topk_contraction_property(frac, m, n, seed):
 
 def test_empirical_alpha_helper(key):
     x = jax.random.normal(key, (16, 16))
-    a = C.empirical_alpha(C.TopK(0.25), key, x)
+    a = C.empirical_alpha(C.TopK(0.25, use_pallas=False), key, x)
     assert 0.25 <= a <= 1.0

@@ -15,7 +15,7 @@ from repro.core.error_feedback import apply_payload, ef_compress_step
 
 def test_ef_state_bit_consistency(key):
     """Sender and receiver estimates stay identical (the EF21 invariant)."""
-    comp = TopK(0.2)
+    comp = TopK(0.2, use_pallas=False)
     target = jax.random.normal(key, (12, 12))
     est_send = jnp.zeros((12, 12))
     est_recv = jnp.zeros((12, 12))
@@ -31,7 +31,7 @@ def test_ef_state_bit_consistency(key):
 def test_ef_estimate_converges_to_fixed_target(key):
     """Repeated EF rounds on a fixed target: ||G - T|| -> 0 geometrically
     (contraction factor sqrt(1 - alpha))."""
-    comp = TopK(0.25)
+    comp = TopK(0.25, use_pallas=False)
     target = jax.random.normal(key, (20, 20))
     est = jnp.zeros_like(target)
     state = comp.init(key, target.shape, jnp.float32)
@@ -59,7 +59,7 @@ def test_biased_compression_without_ef_fails(key):
     """Top1-compressed gradient descent (no EF) stalls/diverges on the
     quadratic example while EF21 converges to the optimum."""
     grad_j = _quadratic_problem()
-    comp = TopK(0.34)  # top-1 of 3
+    comp = TopK(0.34, use_pallas=False)  # top-1 of 3
     lr = 0.1
 
     def naive(x0, steps=300):

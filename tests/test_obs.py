@@ -235,9 +235,17 @@ def test_metrics_on_bit_equal_and_content(key):
     # the incompressible identity leaf has zero EF error by construction
     assert vals["ef/err_norm/bias"] == 0.0
     assert vals["ef/rel_err/bias"] == 0.0
-    # static wire accounting matches the layouts exactly
+    # TopK leaves count the kept entries tied at the threshold: at least
+    # one a slice, at most all k; the identity leaf has no such count
     plan = LayerPlan.build(params, metas, w2s="top10+natural",
                            s2w="natural", use_pallas=False)
+    for leaf, lp in zip(leaf_names(params), plan.leaves):
+        if leaf == "bias":
+            assert "ef/topk_ties/bias" not in ms
+            continue
+        k = lp.w2s.inner.k_for(lp.slice_shape)
+        assert lp.n_stack <= vals[f"ef/topk_ties/{leaf}"] <= k * lp.n_stack
+    # static wire accounting matches the layouts exactly
     dt = _opt().cfg.wire_dtype
     assert vals["wire/bytes_w2s"] == plan.wire_layout(dt).total_nbytes
     assert vals["wire/bytes_s2w"] == \

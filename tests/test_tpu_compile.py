@@ -10,6 +10,7 @@ the TPU compiler.
 """
 import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -19,7 +20,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import bitpack as bp
 from repro.kernels.ops import (natural_compress, natural_decompress,
-                               newton_schulz_batched)
+                               newton_schulz_batched, topk_select)
 
 D_MODEL, D_FF = 768, 3072
 K_TOP10 = math.ceil(0.1 * D_MODEL * D_FF)   # top10 of one MLP slice
@@ -93,6 +94,22 @@ def test_narrow_codec_compiles_for_v5e(width, k, one_chip):
              one_chip, ((k,), jnp.int32))
     _compile(lambda b: bp.narrow_decode(b, width, use_pallas=True),
              one_chip, ((width * k,), jnp.uint8))
+
+
+# the embedding slice and a stack of MLP slices, both workers, flattened
+# under the vmaps the optimizer adds: the threshold passes and the
+# compaction kernel, and no sort
+@pytest.mark.parametrize("shape", [(2, 50304, D_MODEL),
+                                   (2, 12, D_MODEL, D_FF)])
+def test_topk_select_compiles_for_v5e(shape, one_chip):
+    k = math.ceil(0.1 * shape[-2] * shape[-1])
+    fn = lambda s: topk_select(s.reshape(-1), k,  # noqa: E731
+                               use_pallas=True)
+    for _ in shape[:-2]:
+        fn = jax.vmap(fn)
+    text = _compile(fn, one_chip, (shape, jnp.bfloat16)).as_text()
+    assert "topk_select" in text
+    assert not re.search(r"\bsort\(", text)
 
 
 # Mosaic kernels cannot be partitioned automatically: on a mesh of several

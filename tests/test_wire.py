@@ -170,7 +170,7 @@ def test_narrow_width_selection_per_domain():
 def test_index_domains_column_topk():
     assert index_domains(C.ColumnTopK(0.1), (128, 300)) == {"indices": 300}
     assert index_domains(
-        C.WithNatural(C.TopK(0.1), use_pallas=False), (16, 8)) == \
+        C.WithNatural(C.TopK(0.1, use_pallas=False), use_pallas=False), (16, 8)) == \
         {"indices": 128}
     assert index_domains(C.Natural(use_pallas=False), (16, 8)) == {}
 
